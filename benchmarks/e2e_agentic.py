@@ -87,8 +87,7 @@ def run(n_rows: int = 20_000, cv_k: int = 3, spill_dir: str | None = None,
 
     # ---- stratum -----------------------------------------------------------
     batch, ctx = _workload(n_rows, cv_k)
-    s = Stratum(memory_budget_bytes=4 << 30, spill_dir=spill_dir,
-                jit_cache_dir="/tmp/repro_jit_cache")
+    s = Stratum(memory_budget_bytes=4 << 30, spill_dir=spill_dir)
     t0 = time.perf_counter()
     res1, rep1 = s.run_batch(batch)
     best = min(res1, key=lambda k: float(np.asarray(res1[k])))
@@ -160,25 +159,22 @@ def run_service(n_agents: int = 4, n_rows: int = 20_000, cv_k: int = 3,
     """4-sequential-sessions baseline vs N agents through one service."""
     from repro.data.tabular import ensure_files
     ensure_files("uk_housing", n_rows, 0)
-    jit_dir = "/tmp/repro_jit_cache"
 
     if warmup:  # warm the XLA jit cache so neither mode pays compile time
         _run_one_agent(
-            Stratum(memory_budget_bytes=4 << 30,
-                    jit_cache_dir=jit_dir).run_batch, n_rows, cv_k, 0)
+            Stratum(memory_budget_bytes=4 << 30).run_batch, n_rows, cv_k, 0)
 
     # ---- baseline: N isolated, sequential Stratum sessions ---------------
     t0 = time.perf_counter()
     seq_scores = []
     for i in range(n_agents):
-        session = Stratum(memory_budget_bytes=4 << 30, jit_cache_dir=jit_dir)
+        session = Stratum(memory_budget_bytes=4 << 30)
         seq_scores.append(
             _run_one_agent(session.run_batch, n_rows, cv_k, i))
     sequential_s = time.perf_counter() - t0
 
     # ---- service: N concurrent agents over one optimizing runtime --------
     svc = StratumService(memory_budget_bytes=4 << 30,
-                         jit_cache_dir=jit_dir,
                          coalesce_window_s=0.05,
                          n_executors=2)
     svc_scores = [None] * n_agents
@@ -286,7 +282,7 @@ def _balanced_cohort_keys(n_cohorts: int, n_shards: int, vnodes: int = 64
 
 
 def _run_fabric_mode(n_shards: int, n_agents: int, n_cohorts: int,
-                     rounds: int, n_rows: int, jit_dir: str,
+                     rounds: int, n_rows: int,
                      ring_shards_for_keys: int) -> dict:
     from repro.service import ServiceConfig
     from repro.service.fabric import ShardedStratum
@@ -302,7 +298,6 @@ def _run_fabric_mode(n_shards: int, n_agents: int, n_cohorts: int,
     cfg = ServiceConfig(
         memory_budget_bytes=mem_budget,
         cache_fraction=min(0.5, 1.3 * per_cohort / mem_budget),
-        jit_cache_dir=jit_dir,
         coalesce_window_s=0.005,
         coalesce_max_jobs=2,
         max_jobs_per_tenant_per_round=1,
@@ -376,18 +371,17 @@ def run_sharded(n_agents: int = 16, rounds: int = 3, n_rows: int = 30_000,
     from repro.data.tabular import ensure_files
     for c in range(n_cohorts):
         ensure_files("uk_housing", n_rows, c)
-    jit_dir = "/tmp/repro_jit_cache"
     max_shards = max(shard_counts)
 
     if warmup:   # compile each op shape once so no mode pays XLA compile
-        s = Stratum(memory_budget_bytes=256 << 20, jit_cache_dir=jit_dir,
+        s = Stratum(memory_budget_bytes=256 << 20,
                     compiled_segments=False)   # match the modes' regime
         s.run_batch(_cohort_job(0, n_rows, 0))
 
     modes = {}
     for n_shards in shard_counts:
         modes[str(n_shards)] = _run_fabric_mode(
-            n_shards, n_agents, n_cohorts, rounds, n_rows, jit_dir,
+            n_shards, n_agents, n_cohorts, rounds, n_rows,
             ring_shards_for_keys=max_shards)
 
     lo = modes[str(min(shard_counts))]
@@ -434,13 +428,12 @@ def sharded_rows(smoke: bool = False,
 # ---------------------------------------------------------------------------
 
 def _run_proc_mode(n_procs: int, n_agents: int, n_cohorts: int,
-                   rounds: int, n_rows: int, jit_dir: str,
+                   rounds: int, n_rows: int,
                    ring_shards_for_keys: int) -> dict:
     from repro.service import ServiceConfig
     from repro.service.fabric import ProcConfig, ProcStratumFabric
     cfg = ServiceConfig(
         memory_budget_bytes=256 << 20,
-        jit_cache_dir=jit_dir,
         coalesce_window_s=0.005,
         coalesce_max_jobs=2,
         max_jobs_per_tenant_per_round=1,
@@ -499,13 +492,12 @@ def run_proc_fabric(n_agents: int = 8, rounds: int = 3, n_rows: int = 20_000,
     from repro.data.tabular import ensure_files
     for c in range(n_cohorts):
         ensure_files("uk_housing", n_rows, c)
-    jit_dir = "/tmp/repro_jit_cache"
     max_procs = max(proc_counts)
 
     modes = {}
     for n_procs in proc_counts:
         modes[str(n_procs)] = _run_proc_mode(
-            n_procs, n_agents, n_cohorts, rounds, n_rows, jit_dir,
+            n_procs, n_agents, n_cohorts, rounds, n_rows,
             ring_shards_for_keys=max_procs)
 
     lo = modes[str(min(proc_counts))]
@@ -580,9 +572,8 @@ def _refinement_batch(round_i: int, n_variants: int, n_rows: int
 
 
 def _compiled_mode(compiled: bool, rounds: int, n_variants: int,
-                   n_rows: int, jit_dir: str, **svc_kw) -> dict:
+                   n_rows: int, **svc_kw) -> dict:
     svc = StratumService(memory_budget_bytes=2 << 30,
-                         jit_cache_dir=jit_dir,
                          coalesce_window_s=0.0,
                          n_executors=1,
                          compiled_segments=compiled,
@@ -630,9 +621,8 @@ def run_compiled(rounds: int = 10, n_variants: int = 8,
     changes dispatch granularity, never semantics."""
     from repro.data.tabular import ensure_files
     ensure_files("uk_housing", n_rows, 0)
-    jit_dir = "/tmp/repro_jit_cache"
-    per_op = _compiled_mode(False, rounds, n_variants, n_rows, jit_dir)
-    comp = _compiled_mode(True, rounds, n_variants, n_rows, jit_dir)
+    per_op = _compiled_mode(False, rounds, n_variants, n_rows)
+    comp = _compiled_mode(True, rounds, n_variants, n_rows)
     max_rel = max(abs(a - b) / max(abs(a), 1e-12)
                   for a, b in zip(comp["scores"], per_op["scores"]))
     out = {
@@ -680,38 +670,26 @@ def compiled_rows(smoke: bool = False,
 # across the fan, vs the unrolled whole-segment jit and per-op dispatch
 # ---------------------------------------------------------------------------
 
-def _in_fresh_interpreter(fn_name: str, *args):
-    """Run one module-level helper of this file in a FRESH python
-    interpreter and return its JSON-decoded result.
+def _cold_in_process(fn, *args):
+    """Run ``fn(*args)`` cold in this process and return its result.
 
     Cold-start numbers measured inside the long-lived multi-section
-    bench process are fiction: earlier sections leave the persistent
-    XLA cache initialized (``jax.config.update(...)`` cannot fully
-    un-initialize it), XLA's in-process compilation machinery warm, and
-    enough allocator/thread residue to swing first-touch latency
-    severalfold between runs.  A fresh interpreter is what a cold agent
-    service actually is, and makes the numbers reproducible regardless
-    of which sections ran before."""
-    import subprocess
-    import sys
-    here = os.path.dirname(os.path.abspath(__file__))
-    paths = [here, os.path.abspath(os.path.join(here, "..", "src"))]
-    code = (f"import sys\nsys.path[:0] = {paths!r}\n"
-            "import json\n"
-            "import e2e_agentic as m\n"
-            f"r = getattr(m, {fn_name!r})(*{args!r})\n"
-            "print('RESULT ' + json.dumps(r))\n")
-    env = dict(os.environ)
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)  # cold means cold
-    proc = subprocess.run([sys.executable, "-c", code], text=True,
-                          capture_output=True, env=env, timeout=1200)
-    if proc.returncode != 0:
-        raise RuntimeError(f"{fn_name}{args} subprocess failed:\n"
-                           f"{proc.stderr[-2000:]}")
-    for line in reversed(proc.stdout.splitlines()):
-        if line.startswith("RESULT "):
-            return json.loads(line[len("RESULT "):])
-    raise RuntimeError(f"{fn_name}{args} subprocess printed no result")
+    bench process are fiction unless every compile is really paid: so
+    JAX's in-memory compile caches are cleared and its persistent
+    compilation cache is switched off for the call, then restored.  The
+    measurement stays in this process because a child interpreter cannot
+    take an accelerator this process already holds."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    jax.clear_caches()
+    try:
+        return fn(*args)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
 
 
 def _cold_first_touch_s(batch_variants: bool, n_variants: int,
@@ -720,8 +698,8 @@ def _cold_first_touch_s(batch_variants: bool, n_variants: int,
     with blocking compiles and no persistent XLA cache: pure trace+jit
     cost of one refinement fan (plus one warm execution).  A tiny jit
     call first charges backend/LLVM bring-up to setup, not to the
-    measured fan.  Meaningful only in a fresh interpreter — run it via
-    ``_in_fresh_interpreter``."""
+    measured fan.  Meaningful only cold — run it via
+    ``_cold_in_process``."""
     import jax
     jax.block_until_ready(jax.jit(lambda v: v + 1.0)(
         np.zeros(8, np.float32)))
@@ -756,20 +734,19 @@ def run_compiled_batched(rounds: int = 10, n_variants: int = 8,
     section actually claims.  Makespans stay in the artifact."""
     from repro.data.tabular import ensure_files
     ensure_files("uk_housing", n_rows, 0)
-    jit_dir = "/tmp/repro_jit_cache"
-    per_op = _compiled_mode(False, rounds, n_variants, n_rows, jit_dir)
-    comp = _compiled_mode(True, rounds, n_variants, n_rows, jit_dir)
-    bat = _compiled_mode(True, rounds, n_variants, n_rows, jit_dir,
+    per_op = _compiled_mode(False, rounds, n_variants, n_rows)
+    comp = _compiled_mode(True, rounds, n_variants, n_rows)
+    bat = _compiled_mode(True, rounds, n_variants, n_rows,
                          batch_variants=True)
     max_rel = max(abs(a - b) / max(abs(a), 1e-12)
                   for a, b in zip(bat["scores"], per_op["scores"]))
-    # cold-compile comparison: each layout in its own fresh interpreter,
-    # so neither this process's warm XLA state nor the other layout's
+    # cold-compile comparison: each layout with every compile cache
+    # cleared, so neither the sections above nor the other layout's
     # compile can contaminate the first touch
-    cold_unrolled = _in_fresh_interpreter(
-        "_cold_first_touch_s", False, n_variants, n_rows)
-    cold_batched = _in_fresh_interpreter(
-        "_cold_first_touch_s", True, n_variants, n_rows)
+    cold_unrolled = _cold_in_process(
+        _cold_first_touch_s, False, n_variants, n_rows)
+    cold_batched = _cold_in_process(
+        _cold_first_touch_s, True, n_variants, n_rows)
     return {
         "rounds": rounds, "variants": n_variants, "rows": n_rows,
         "modes": {
@@ -899,10 +876,10 @@ def run_compiled_cold(n_structs: int = 4, reps: int = 6,
     pays trace+jit inside the measured first round of every structure;
     ``compile_async=True`` plus a speculative warm-up hint during agent
     think time keeps the first touch on warm programs.  Each mode runs
-    in its own fresh interpreter (no persistent XLA cache, no residue
-    from other bench sections or from the other mode's compiles of the
-    same structures) so every compile is real — see
-    ``_in_fresh_interpreter``.
+    with every compile cache cleared and the persistent XLA cache off (no
+    residue from other bench sections or from the other mode's compiles
+    of the same structures) so every compile is real — see
+    ``_cold_in_process``.
 
     Gating: ``speculative_hits`` (one per structure — deterministic:
     every measured first touch must land on a speculatively compiled
@@ -913,10 +890,10 @@ def run_compiled_cold(n_structs: int = 4, reps: int = 6,
     gate."""
     from repro.data.tabular import ensure_files
     ensure_files("uk_housing", n_rows, 0)
-    blocking = _in_fresh_interpreter(
-        "_cold_mode", False, n_structs, reps, n_variants, n_rows)
-    async_m = _in_fresh_interpreter(
-        "_cold_mode", True, n_structs, reps, n_variants, n_rows)
+    blocking = _cold_in_process(
+        _cold_mode, False, n_structs, reps, n_variants, n_rows)
+    async_m = _cold_in_process(
+        _cold_mode, True, n_structs, reps, n_variants, n_rows)
     # the conservative warm reference: slower of the two modes' warm p99
     warm_p99 = max(blocking["warm_p99_s"], async_m["warm_p99_s"])
     return {
@@ -958,7 +935,7 @@ def compiled_cold_rows(smoke: bool = False,
 def _deadline_mode(deadline_aware: bool, n_rows: int, n_cohorts: int,
                    n_bulk_agents: int, sweeps_per_agent: int,
                    probe_rows: int, deadline_s: float,
-                   probe_interval_s: float, jit_dir: str) -> dict:
+                   probe_interval_s: float) -> dict:
     """One mode of the deadline benchmark: bulk tenants flood the BATCH
     band with deadline-free cohort sweeps while one tenant submits
     sequential probes carrying ``deadline_s`` — the SAME band, so WFQ
@@ -983,7 +960,6 @@ def _deadline_mode(deadline_aware: bool, n_rows: int, n_cohorts: int,
     mem_budget = 256 << 20
     svc = StratumService(memory_budget_bytes=mem_budget,
                          cache_fraction=1e-5,    # see docstring: flat cost
-                         jit_cache_dir=jit_dir,
                          coalesce_window_s=0.02,
                          coalesce_max_jobs=2,
                          max_jobs_per_tenant_per_round=1,
@@ -1100,16 +1076,15 @@ def run_deadline(n_rows: int = 30_000, n_cohorts: int = 6,
     for c in range(n_cohorts):
         ensure_files("uk_housing", n_rows, c)
     ensure_files("uk_housing", probe_rows, 0)
-    jit_dir = "/tmp/repro_jit_cache"
 
     if warmup:   # compile the jax kernels once so neither mode pays for it
-        s = Stratum(memory_budget_bytes=4 << 30, jit_cache_dir=jit_dir)
+        s = Stratum(memory_budget_bytes=4 << 30)
         s.run_batch(_cohort_job(0, n_rows, 0))
         for i in range(4):                  # probes rotate column sets;
             s.run_batch(_probe_batch(i, probe_rows))   # compile each shape
 
     args = (n_rows, n_cohorts, n_bulk_agents, sweeps_per_agent, probe_rows,
-            deadline_s, probe_interval_s, jit_dir)
+            deadline_s, probe_interval_s)
     # interleave repetitions (blind, aware, blind, aware) and pool: one
     # fixed-work run is short enough that XLA/GC noise moves its makespan
     # by whole seconds, and the modes must not sit on opposite sides of a
@@ -1199,7 +1174,7 @@ def deadline_rows(smoke: bool = False,
 def _control_mode(controlled: bool, n_rows: int, n_cohorts: int,
                   n_bulk_agents: int, steady_sweeps: int, flood_sweeps: int,
                   probe_rows: int, deadline_s: float,
-                  probe_interval_s: float, jit_dir: str) -> dict:
+                  probe_interval_s: float) -> dict:
     """One mode of the control benchmark: a two-phase workload against a
     service whose admission gate is deliberately small
     (``max_queued_total=16``).
@@ -1243,7 +1218,6 @@ def _control_mode(controlled: bool, n_rows: int, n_cohorts: int,
     # who pays the probes' recompute
     svc = StratumService(memory_budget_bytes=mem_budget,
                          cache_fraction=2e-3,
-                         jit_cache_dir=jit_dir,
                          # 5ms window: at 0.1s/job a long gather would
                          # idle an executor slot every time a solo probe
                          # or a thin band pops (the slot is held while
@@ -1396,11 +1370,10 @@ def run_control(n_rows: int = 12_000, n_cohorts: int = 4,
     for c in range(n_cohorts):
         ensure_files("uk_housing", n_rows, c)
     ensure_files("uk_housing", probe_rows, 0)
-    jit_dir = "/tmp/repro_jit_cache"
 
     if warmup:
         # compile the jax kernels once so neither mode pays for it
-        s = Stratum(memory_budget_bytes=4 << 30, jit_cache_dir=jit_dir)
+        s = Stratum(memory_budget_bytes=4 << 30)
         for c in range(n_cohorts):
             s.run_batch(_cohort_job(c, n_rows, c))
         for i in range(4):                  # probes rotate column sets
@@ -1411,7 +1384,7 @@ def run_control(n_rows: int = 12_000, n_cohorts: int = 4,
         # rep order (static first) would book all of that cold cost to
         # the static mode, corrupting the throughput ratio
         warm = StratumService(memory_budget_bytes=256 << 20,
-                              cache_fraction=1e-5, jit_cache_dir=jit_dir,
+                              cache_fraction=1e-5,
                               coalesce_window_s=0.02, coalesce_max_jobs=2,
                               max_jobs_per_tenant_per_round=1,
                               n_executors=2, aging_s=None)
@@ -1428,7 +1401,7 @@ def run_control(n_rows: int = 12_000, n_cohorts: int = 4,
             warm.stop()
 
     args = (n_rows, n_cohorts, n_bulk_agents, steady_sweeps, flood_sweeps,
-            probe_rows, deadline_s, probe_interval_s, jit_dir)
+            probe_rows, deadline_s, probe_interval_s)
     # interleave repetitions and pool (same rationale as the deadline
     # bench: fixed-work makespans drift with machine state), ALTERNATING
     # which mode runs first in each pair: in-process drift biases the
@@ -1550,14 +1523,12 @@ def _sweep_batch(agent: int, j: int, n_rows: int, cv_k: int
 
 def _mixed_priority_mode(priority_aware: bool, n_rows: int, cv_k: int,
                          n_batch_agents: int,
-                         n_probes: int, probe_rows: int,
-                         jit_dir: str) -> dict:
+                         n_probes: int, probe_rows: int) -> dict:
     # small super-batches (2 jobs) keep both executors continuously busy
     # with queued sweep work behind them — the contended regime the
     # scheduler exists for; aging is disabled so the measurement isolates
     # WFQ + preemption (the scavenger band still progresses via weight 1)
     svc = StratumService(memory_budget_bytes=4 << 30,
-                         jit_cache_dir=jit_dir,
                          coalesce_window_s=0.02,
                          coalesce_max_jobs=2,
                          max_jobs_per_tenant_per_round=1,
@@ -1643,17 +1614,16 @@ def run_mixed_priority(n_rows: int = 8000, cv_k: int = 2,
     from repro.data.tabular import ensure_files
     ensure_files("uk_housing", n_rows, 0)
     ensure_files("uk_housing", probe_rows, 0)
-    jit_dir = "/tmp/repro_jit_cache"
 
     if warmup:   # compile the jax kernels once so neither mode pays for it
-        s = Stratum(memory_budget_bytes=4 << 30, jit_cache_dir=jit_dir)
+        s = Stratum(memory_budget_bytes=4 << 30)
         s.run_batch(_sweep_batch(0, 0, n_rows, cv_k))
         s.run_batch(_probe_batch(0, probe_rows))
 
     blind = _mixed_priority_mode(False, n_rows, cv_k, n_batch_agents,
-                                 n_probes, probe_rows, jit_dir)
+                                 n_probes, probe_rows)
     aware = _mixed_priority_mode(True, n_rows, cv_k, n_batch_agents,
-                                 n_probes, probe_rows, jit_dir)
+                                 n_probes, probe_rows)
     scores_identical = all(
         abs(a - b) <= 1e-9 * max(abs(a), 1.0)
         for a, b in zip(aware["scores"], blind["scores"]))
@@ -1715,13 +1685,12 @@ def service_rows(n_agents: int = 4, n_rows: int = 20_000,
 # ---------------------------------------------------------------------------
 
 def _traced_mode(traced: bool, rounds: int, n_variants: int, n_rows: int,
-                 jit_dir: str, trace_dir=None) -> dict:
+                 trace_dir=None) -> dict:
     """One mode of the observability benchmark: the compiled section's
     repeated-structure refinement workload, with per-job lifecycle
     tracing (and, when ``trace_dir`` is set, the flushed JSONL event
     log) either on or off.  Everything else is held identical."""
     svc = StratumService(memory_budget_bytes=2 << 30,
-                         jit_cache_dir=jit_dir,
                          coalesce_window_s=0.0,
                          n_executors=1,
                          trace=traced,
@@ -1731,6 +1700,11 @@ def _traced_mode(traced: bool, rounds: int, n_variants: int, n_rows: int,
         for w in (rounds, rounds + 1):        # warmup (see _compiled_mode)
             ses.submit(_refinement_batch(w, n_variants, n_rows)
                        ).result(timeout=600)
+        # the measured window is a few milliseconds: a full collection of
+        # the process heap (tens of ms, whichever mode's allocations
+        # trigger it) would otherwise land in one mode's window
+        import gc
+        gc.collect()
         t0 = time.perf_counter()
         for r in range(rounds):
             _, rep = ses.submit(_refinement_batch(r, n_variants, n_rows)
@@ -1760,10 +1734,9 @@ def run_observability(rounds: int = 8, n_variants: int = 6,
 
     from repro.data.tabular import ensure_files
     ensure_files("uk_housing", n_rows, 0)
-    jit_dir = "/tmp/repro_jit_cache"
-    untraced = _traced_mode(False, rounds, n_variants, n_rows, jit_dir)
+    untraced = _traced_mode(False, rounds, n_variants, n_rows)
     with tempfile.TemporaryDirectory() as td:
-        traced = _traced_mode(True, rounds, n_variants, n_rows, jit_dir,
+        traced = _traced_mode(True, rounds, n_variants, n_rows,
                               trace_dir=td)
         timelines = replay.reassemble(replay.load_events(td))
         jobs_traced = len(timelines)
@@ -1822,7 +1795,7 @@ def _invalid_batch(i: int, n_rows: int) -> PipelineBatch:
 
 
 def _analysis_mode(admission: bool, rounds: int, n_variants: int,
-                   n_rows: int, invalid_every: int, jit_dir: str) -> dict:
+                   n_rows: int, invalid_every: int) -> dict:
     """One mode of the analysis benchmark: the compiled section's
     repeated-structure refinement flood with a fixed fraction of
     statically-invalid submissions mixed in, admission analysis either
@@ -1830,7 +1803,6 @@ def _analysis_mode(admission: bool, rounds: int, n_variants: int,
     ``submit`` to the invalid jobs' verdicts."""
     from repro.core.analysis import AnalysisError
     svc = StratumService(memory_budget_bytes=2 << 30,
-                         jit_cache_dir=jit_dir,
                          coalesce_window_s=0.0,
                          n_executors=1,
                          admission_analysis=admission)
@@ -1912,15 +1884,14 @@ def run_analysis(rounds: int = 8, n_variants: int = 6, n_rows: int = 3000,
     hovers around 1.0 and is not a stable gate."""
     from repro.data.tabular import ensure_files
     ensure_files("uk_housing", n_rows, 0)
-    jit_dir = "/tmp/repro_jit_cache"
     # alternate modes and keep each mode's best repeat: a single ~1s
     # makespan flakes on scheduler/compile noise, the min of two does not
     plain = analyzed = None
     for _ in range(repeats):
         p = _analysis_mode(False, rounds, n_variants, n_rows,
-                           invalid_every, jit_dir)
+                           invalid_every)
         a = _analysis_mode(True, rounds, n_variants, n_rows,
-                           invalid_every, jit_dir)
+                           invalid_every)
         if plain is None or p["makespan_s"] < plain["makespan_s"]:
             plain = p
         if analyzed is None or a["makespan_s"] < analyzed["makespan_s"]:

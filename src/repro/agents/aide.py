@@ -186,14 +186,16 @@ def diff_fraction(a: "PipelineSpec", b: "PipelineSpec") -> float:
 
 def paper_workload_batches(n_rows: int = 30_000, cv_k: int = 3,
                            seed: int = 7,
-                           best_hint: Optional[tuple] = None
+                           best_hint: Optional[tuple] = None,
+                           data_seed: int = 0
                            ) -> Iterator[tuple[str, PipelineBatch, dict]]:
     """Yields (iteration_name, batch, context).  The caller runs iteration 1,
     selects the best (preproc, model), and passes results back via ``send``
     — implemented instead as a two-phase generator protocol: iteration 2 is
-    produced by :func:`second_iteration_batch` given iteration-1 scores."""
+    produced by :func:`second_iteration_batch` given iteration-1 scores.
+    ``data_seed`` selects the generated table, ``seed`` the CV folds."""
     specs = [PipelineSpec(preproc=p, model=m, cv_k=cv_k, n_rows=n_rows,
-                          seed=seed)
+                          data_seed=data_seed, seed=seed)
              for p in PREPROCS for m in MODELS]
     names = [f"{s.preproc}+{s.model}" for s in specs]
     batch = PipelineBatch([s.build() for s in specs], names)
@@ -229,9 +231,11 @@ class AIDEAgent:
     matching Fig. 2a)."""
 
     def __init__(self, n_rows: int = 30_000, cv_k: int = 3, seed: int = 0,
-                 n_drafts: int = 4, explore_first: bool = True):
+                 n_drafts: int = 4, explore_first: bool = True,
+                 data_seed: int = 0):
         self.rng = random.Random(seed)
-        self.base = PipelineSpec(n_rows=n_rows, cv_k=cv_k, seed=7)
+        self.base = PipelineSpec(n_rows=n_rows, cv_k=cv_k, seed=7,
+                                 data_seed=data_seed)
         self.n_drafts = n_drafts
         self.explore_first = explore_first
         self.nodes: list[SearchNode] = []

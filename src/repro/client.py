@@ -140,7 +140,6 @@ class RuntimeConfig:
     """Execution resources and the compiled-segment regime."""
     memory_budget_bytes: int = 8 << 30
     hardware_threads: int = 0            # 0 → os.cpu_count()
-    jit_cache_dir: Optional[str] = None
     compiled_segments: bool = True
     plan_cache_entries: int = _DEFAULT_PLAN_CACHE_ENTRIES
     # bound a compiled segment's est_time so it can never delay an
@@ -270,7 +269,6 @@ class StratumConfig:
             "platform": self.optimizer.platform,
             "enable": self.optimizer.enable,
             "hardware_threads": self.runtime.hardware_threads,
-            "jit_cache_dir": self.runtime.jit_cache_dir,
             "compiled_segments": self.runtime.compiled_segments,
             "segment_time_budget_s": self.runtime.segment_time_budget_s,
         }
@@ -298,7 +296,6 @@ class StratumConfig:
             platform=self.optimizer.platform,
             enable=self.optimizer.enable,
             hardware_threads=self.runtime.hardware_threads,
-            jit_cache_dir=self.runtime.jit_cache_dir,
             max_queued_total=s.max_queued_total,
             max_queued_per_tenant=s.max_queued_per_tenant,
             admission_analysis=s.admission_analysis,

@@ -12,9 +12,11 @@ backends), ``parallel`` (inter-op), ``cache`` (intermediate reuse).
 
 from __future__ import annotations
 
+import os
 import time
 import warnings
 from dataclasses import dataclass
+from pathlib import Path
 from typing import Any, Optional, Sequence
 
 from .analysis import AnalysisError, AnalysisReport, analyze, validate_wiring
@@ -66,6 +68,29 @@ class StratumReport:
         return "\n".join(lines)
 
 
+#: Where the persistent XLA compilation cache lives when the environment
+#: does not say: one fixed directory in the checkout (``src/repro/core`` is
+#: three levels below it), so every process of this checkout shares it.
+JAX_CACHE_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+
+
+def resolve_compilation_cache() -> Optional[str]:
+    """Place JAX's persistent compilation cache, the analogue of the
+    paper's precompiled kernels: a long-lived service compiles each
+    segment shape once across sessions and processes.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    this sets nothing (returns None).  Otherwise the cache goes to
+    :data:`JAX_CACHE_DIR`, which is returned."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    import jax
+    if jax.config.jax_compilation_cache_dir != JAX_CACHE_DIR:
+        jax.config.update("jax_compilation_cache_dir", JAX_CACHE_DIR)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+    return JAX_CACHE_DIR
+
+
 _DEFAULT_CACHE_FRACTION = 0.10      # paper default
 _DEFAULT_PLAN_CACHE_ENTRIES = 256
 _warned_once: set = set()
@@ -95,7 +120,6 @@ class Stratum:
                  platform: str = "",
                  enable: Sequence[str] = ALL_FEATURES,
                  hardware_threads: int = 0,
-                 jit_cache_dir: Optional[str] = None,
                  cache: Optional[IntermediateCache] = None,
                  compiled_segments: bool = True,
                  plan_cache: Optional[PlanCache] = None,
@@ -137,14 +161,7 @@ class Stratum:
             cache_fraction = _DEFAULT_CACHE_FRACTION
         if plan_cache_entries is None:
             plan_cache_entries = _DEFAULT_PLAN_CACHE_ENTRIES
-        if jit_cache_dir:
-            # persistent XLA compilation cache: a long-lived stratum service
-            # compiles each (op, shape) once across sessions/processes —
-            # the analogue of the paper's precompiled Rust kernels
-            import jax
-            jax.config.update("jax_compilation_cache_dir", jit_cache_dir)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.1)
+        resolve_compilation_cache()
         self.enable = tuple(enable)
         self.memory_budget_bytes = memory_budget_bytes
         self.platform = platform

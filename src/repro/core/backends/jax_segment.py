@@ -60,9 +60,13 @@ rates stay honest, in an LRU bounded by ``uncompilable_max`` so an
 adversarial stream of distinct bad shapes cannot grow a shard's memory —
 and runs per-op forever after (a batched build that fails its probe first
 retries unbatched before giving up); a *runtime* failure of a compiled
-program (possibly transient, e.g. resource exhaustion) falls back per-op
-for that round only, reproducing any precise per-op error exactly as the
-uncompiled path would.
+program (possibly transient, e.g. resource exhaustion, or a compile the
+device refuses on the first call) falls back per-op for that round only,
+reproducing any precise per-op error exactly as the uncompiled path
+would.  Each such round counts in ``RunReport.segment_runtime_failures``
+and the plan cache's ``runtime_failures`` (failed background warm calls
+in ``warm_failures``), so a device that refuses the compiled path is
+visible in telemetry.
 """
 
 from __future__ import annotations
@@ -254,16 +258,37 @@ class JaxSegmentBackend(ExecutionBackend):
         with self._unc_lock:
             return key in self._preverified
 
-    def preverify_segment(self, segment, selection, infos):
-        """Statically discharge a segment's first-dispatch probe.
+    def _program_of(self, compute, selection):
+        """What defines the program of a compute set, for every path that
+        builds, compiles or looks one up: ``(key, in_specs, ext_keys,
+        hoists, hoist_vals)``.  The key holds the structure of every
+        traced op, the cut (which inputs are external) and the exact impl
+        chosen (fidelity annotations can swap impls between structurally
+        identical plans)."""
+        in_specs, ext_keys = self._wiring(compute)
+        hoists = tuple(tuple(sorted(tunable_fields(op.op_name)
+                                    & set(op.spec))) for op in compute)
+        ssigs = tuple(op.structural_signature for op in compute)
+        impl_ids = tuple(id(selection[op.signature]) for op in compute)
+        key = (self._key_tag, ssigs, in_specs, impl_ids)
+        hoist_vals = tuple(op.spec[f]
+                           for op, fs in zip(compute, hoists)
+                           for f in fs)
+        return key, in_specs, ext_keys, hoists, hoist_vals
 
-        Builds the segment program exactly as ``_run_compiled`` would and
-        ``eval_shape``-probes it on the analyzer's inferred input avals
-        (``infos``: op signature -> list[TensorInfo]).  On success the
-        plan-cache key is marked pre-verified and returned; on failure
-        returns None and changes nothing — inferred avals may be less
-        precise than runtime values, so a static miss must never poison
-        the runtime's own probe.  Never executes or compiles."""
+    @staticmethod
+    def _sources(compute, selection):
+        """Trace stand-ins and impl functions of a compute set: all a
+        build reads, so a cached program never pins the submitted DAG."""
+        return ([_TracedOp.of(op) for op in compute],
+                [selection[op.signature].fn for op in compute])
+
+    def segment_program(self, segment, selection):
+        """The unbatched program a first dispatch of ``segment`` builds
+        when no op of it is served from a cache: ``(key, seg_fn, jitted,
+        ext_refs, hoist_vals)``, where ``ext_refs`` holds one LazyRef per
+        runtime argument, in argument order.  None when an op has no
+        selected impl.  Builds only; never traces or compiles."""
         compute: list = []
         produced: set = set()
         for wave in segment.waves:
@@ -275,33 +300,39 @@ class JaxSegmentBackend(ExecutionBackend):
         if not compute or any(op.signature not in selection
                               for op in compute):
             return None
-        in_specs, ext_keys = self._wiring(compute)
-        hoists = tuple(tuple(sorted(tunable_fields(op.op_name)
-                                    & set(op.spec))) for op in compute)
-        ssigs = tuple(op.structural_signature for op in compute)
-        impl_ids = tuple(id(selection[op.signature]) for op in compute)
-        key = (self._key_tag, ssigs, in_specs, impl_ids)
-        ext_info: dict = {}
-        for op in compute:
-            for r in op.inputs:
-                if r.op.signature in produced:
-                    continue
+        key, in_specs, ext_keys, hoists, hoist_vals = self._program_of(
+            compute, selection)
+        ref_by_key = {r.signature: r for op in compute for r in op.inputs
+                      if r.op.signature not in produced}
+        seg_fn, jitted = self._build(*self._sources(compute, selection),
+                                     in_specs, hoists, ())
+        return (key, seg_fn, jitted, [ref_by_key[k] for k in ext_keys],
+                hoist_vals)
+
+    def preverify_segment(self, segment, selection, infos):
+        """Statically discharge a segment's first-dispatch probe.
+
+        Builds the segment program exactly as ``_run_compiled`` would and
+        ``eval_shape``-probes it on the analyzer's inferred input avals
+        (``infos``: op signature -> list[TensorInfo]).  On success the
+        plan-cache key is marked pre-verified and returned; on failure
+        returns None and changes nothing — inferred avals may be less
+        precise than runtime values, so a static miss must never poison
+        the runtime's own probe.  Never executes or compiles."""
+        try:
+            program = self.segment_program(segment, selection)
+            if program is None:
+                return None
+            key, seg_fn, _jitted, ext_refs, hoist_example = program
+            ext_info = []
+            for r in ext_refs:
                 outs = infos.get(r.op.signature)
                 if outs is None or r.index >= len(outs):
                     return None
-                ext_info[r.signature] = outs[r.index]
-        try:
+                ext_info.append(outs[r.index])
             ext_example = tuple(
-                jax.ShapeDtypeStruct(tuple(ext_info[k].shape),
-                                     np.dtype(ext_info[k].dtype))
-                for k in ext_keys)
-            hoist_example = tuple(op.spec[f]
-                                  for op, fs in zip(compute, hoists)
-                                  for f in fs)
-            protos = [_TracedOp.of(op) for op in compute]
-            impl_fns = [selection[op.signature].fn for op in compute]
-            seg_fn, _jitted = self._build(protos, impl_fns, in_specs,
-                                          hoists, ())
+                jax.ShapeDtypeStruct(tuple(t.shape), np.dtype(t.dtype))
+                for t in ext_info)
             jax.eval_shape(seg_fn, ext_example, hoist_example)
         except Exception:  # noqa: BLE001 — advisory probe, stay silent
             return None
@@ -346,15 +377,8 @@ class JaxSegmentBackend(ExecutionBackend):
     # ------------------------------------------------------------------
     def _run_compiled(self, rt, segment, compute, selection,
                       report) -> None:
-        in_specs, ext_keys = self._wiring(compute)
-        hoists = tuple(tuple(sorted(tunable_fields(op.op_name)
-                                    & set(op.spec))) for op in compute)
-        ssigs = tuple(op.structural_signature for op in compute)
-        impl_ids = tuple(id(selection[op.signature]) for op in compute)
-        # key: structure of every traced op + the cut (which inputs are
-        # external) + the exact impl chosen (fidelity annotations can
-        # swap impls between structurally identical plans)
-        key = (self._key_tag, ssigs, in_specs, impl_ids)
+        key, in_specs, ext_keys, hoists, hoist_vals = self._program_of(
+            compute, selection)
         if self._is_uncompilable(key):
             self._fallback(rt, segment, compute, selection, report)
             return
@@ -362,9 +386,6 @@ class JaxSegmentBackend(ExecutionBackend):
             ext_vals = tuple(rt._values[k] for k in ext_keys)
         if self.plan_cache.executor is not None:
             self._note_ext(ext_keys, ext_vals)
-        hoist_vals = tuple(op.spec[f]
-                           for op, fs in zip(compute, hoists)
-                           for f in fs)
         compiled = self.plan_cache.get(key)
         with rt._lock:
             if compiled is None:
@@ -372,10 +393,9 @@ class JaxSegmentBackend(ExecutionBackend):
             else:
                 report.plan_cache_hits += 1
         if compiled is None:
-            groups = self._plan_groups(ssigs, impl_ids, in_specs, hoists) \
+            groups = self._plan_groups(key[1], key[3], in_specs, hoists) \
                 if self.batch_variants else ()
-            protos = [_TracedOp.of(op) for op in compute]
-            impl_fns = [selection[op.signature].fn for op in compute]
+            protos, impl_fns = self._sources(compute, selection)
             ex = self.plan_cache.executor
             if ex is not None:
                 # async: build off the critical path, run this round
@@ -399,11 +419,16 @@ class JaxSegmentBackend(ExecutionBackend):
             self.plan_cache.put(key, compiled)
         try:
             outs = compiled(ext_vals, hoist_vals)
-        except Exception:  # noqa: BLE001 — XLA runtime failure
+        except Exception:  # noqa: BLE001 — XLA compile or runtime failure
             # possibly transient (e.g. resource exhaustion): run per-op
             # this round WITHOUT forgetting the compiled program — tracing
             # failures were already excluded by the eval_shape probe, so the
-            # next structurally identical plan tries compiled again
+            # next structurally identical plan tries compiled again.  jit
+            # compiles on this first call, so a program the device refuses
+            # lands here too: counted, never silent
+            with rt._lock:
+                report.segment_runtime_failures += 1
+            self.plan_cache.note_runtime_failure()
             self._fallback(rt, segment, compute, selection, report)
             return
         self._commit(rt, compute, outs, selection, report)
@@ -426,7 +451,7 @@ class JaxSegmentBackend(ExecutionBackend):
             try:
                 jax.block_until_ready(jitted(zeros, hoist_vals))
             except Exception:  # noqa: BLE001 — value-dependent on zeros
-                pass
+                self.plan_cache.note_warm_failure()
             self.plan_cache.put(key, jitted, speculative=speculative)
         return job
 
@@ -615,12 +640,8 @@ class JaxSegmentBackend(ExecutionBackend):
                 produced.add(sig)
         if not compute:
             return "empty"
-        in_specs, ext_keys = self._wiring(compute)
-        hoists = tuple(tuple(sorted(tunable_fields(op.op_name)
-                                    & set(op.spec))) for op in compute)
-        ssigs = tuple(op.structural_signature for op in compute)
-        impl_ids = tuple(id(selection[op.signature]) for op in compute)
-        key = (self._key_tag, ssigs, in_specs, impl_ids)
+        key, in_specs, ext_keys, hoists, hoist_vals = self._program_of(
+            compute, selection)
         if self._is_uncompilable(key):
             return "uncompilable"
         if key in self.plan_cache:
@@ -644,13 +665,9 @@ class JaxSegmentBackend(ExecutionBackend):
                 except Exception:  # noqa: BLE001 — no inferred metadata
                     return "no-spec"
             specs.append(a)
-        hoist_vals = tuple(op.spec[f]
-                           for op, fs in zip(compute, hoists)
-                           for f in fs)
-        groups = self._plan_groups(ssigs, impl_ids, in_specs, hoists) \
+        groups = self._plan_groups(key[1], key[3], in_specs, hoists) \
             if self.batch_variants else ()
-        protos = [_TracedOp.of(op) for op in compute]
-        impl_fns = [selection[op.signature].fn for op in compute]
+        protos, impl_fns = self._sources(compute, selection)
         ok = ex.submit(key, self._make_job(
             key, protos, impl_fns, in_specs, hoists, groups,
             tuple(specs), hoist_vals, speculative=True), speculative=True)

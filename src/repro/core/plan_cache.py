@@ -40,6 +40,8 @@ from collections import OrderedDict, deque
 from dataclasses import dataclass
 from typing import Any, Callable, Hashable, Optional
 
+from .placement import on_device
+
 
 @dataclass
 class PlanCacheStats:
@@ -55,6 +57,11 @@ class PlanCacheStats:
     speculative_hits: int = 0    # first demand-hit on a speculative entry
     speculative_dropped: int = 0  # warm-up jobs rejected (lane full)
     uncompilable: int = 0        # gauge: backend's bounded uncompilable set
+    # compiled calls that raised at run time and re-ran per-op, and
+    # background warm calls that raised: a device that refuses a compiled
+    # program shows here instead of passing silently as per-op dispatch
+    runtime_failures: int = 0
+    warm_failures: int = 0
     compile_time_s: float = 0.0  # cumulative seconds in background builds
 
     @property
@@ -82,8 +89,10 @@ class CompileExecutor:
 
     def __init__(self, stats: PlanCacheStats, lock: threading.Lock,
                  contains: Callable[[Hashable], bool],
-                 max_pending: int = 32, speculative_depth: int = 0):
+                 max_pending: int = 32, speculative_depth: int = 0,
+                 device=None):
         self._stats = stats
+        self._device = device       # JAX device the worker compiles for
         self._stats_lock = lock
         self._contains = contains
         self.max_pending = max(1, int(max_pending))
@@ -123,7 +132,8 @@ class CompileExecutor:
                 self._stats.inflight = len(self._inflight)
             if self._worker is None:
                 self._worker = threading.Thread(
-                    target=self._run, name="stratum-compile", daemon=True)
+                    target=on_device(self._device, self._run),
+                    name="stratum-compile", daemon=True)
                 self._worker.start()
             self._mu.notify()
         return True
@@ -208,7 +218,8 @@ class PlanCache:
     warm-up jobs (``speculative_depth`` > 0)."""
 
     def __init__(self, capacity: int = 256, compile_async: bool = False,
-                 max_async_pending: int = 32, speculative_depth: int = 0):
+                 max_async_pending: int = 32, speculative_depth: int = 0,
+                 device=None):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.capacity = capacity
@@ -221,7 +232,7 @@ class PlanCache:
             self.executor = CompileExecutor(
                 self.stats, self._lock, self.__contains__,
                 max_pending=max_async_pending,
-                speculative_depth=speculative_depth)
+                speculative_depth=speculative_depth, device=device)
 
     def get(self, key: Hashable) -> Optional[Any]:
         with self._lock:
@@ -257,6 +268,14 @@ class PlanCache:
         with self._lock:
             self.stats.uncompilable = n
 
+    def note_runtime_failure(self) -> None:
+        with self._lock:
+            self.stats.runtime_failures += 1
+
+    def note_warm_failure(self) -> None:
+        with self._lock:
+            self.stats.warm_failures += 1
+
     def close(self, timeout: float = 5.0) -> None:
         """Shut down the compile executor (no-op when async is off)."""
         if self.executor is not None:
@@ -290,5 +309,7 @@ class PlanCache:
                 "speculative_hits": s.speculative_hits,
                 "speculative_dropped": s.speculative_dropped,
                 "uncompilable": s.uncompilable,
+                "runtime_failures": s.runtime_failures,
+                "warm_failures": s.warm_failures,
                 "compile_time_s": round(s.compile_time_s, 6),
             }

@@ -43,6 +43,7 @@ from typing import Any, Callable, Optional, Sequence
 
 from .cache import IntermediateCache
 from .dag import CONST, LazyOp, LazyRef
+from .placement import on_device
 from .plan_cache import PlanCache
 from .scheduler import Plan, Segment
 from .selection import PhysicalImpl, reference_impl, vmap_group_for
@@ -67,6 +68,10 @@ class RunReport:
     # rounds dispatched per-op while an async compile ran off the critical
     # path (compile_async): cold-start cost shifted, not paid
     plan_cache_fallback_rounds: int = 0
+    # compiled segment calls that raised at run time (a compile refused by
+    # the device, memory exhaustion, a value-dependent failure) and were
+    # re-run per-op: correct results, but not the compiled path
+    segment_runtime_failures: int = 0
 
 
 class ExecutionError(RuntimeError):
@@ -128,7 +133,8 @@ class Runtime:
                  sig_tenant: Optional[dict] = None,
                  plan_cache: Optional[PlanCache] = None,
                  backends: Optional[dict] = None,
-                 compiled_segments: bool = True):
+                 compiled_segments: bool = True,
+                 device=None):
         self.cache = cache
         self.cache_candidates = cache_candidates or set()
         self.parallel = parallel
@@ -145,6 +151,9 @@ class Runtime:
             backends = make_backends(plan_cache,
                                      compiled=compiled_segments)
         self.backends = backends
+        # the JAX device the inter-op pool's threads dispatch to (a fabric
+        # shard's own device); None = the process default
+        self.device = device
         self._values: dict[str, Any] = {}      # "sig:index" -> value
         self._keys_by_sig: dict[str, list[str]] = {}   # sig -> stored keys
         self._skips: set = set()               # resume-skippable ops
@@ -218,7 +227,8 @@ class Runtime:
         finished goes into the salvage."""
         pool = self._pool
         if pool is not None and len(todo) > 1:
-            pending = {pool.submit(self._run_op, op, selection, report)
+            run_op = on_device(self.device, self._run_op)
+            pending = {pool.submit(run_op, op, selection, report)
                        for op in todo}
             while pending:
                 done, pending = _fwait(pending,

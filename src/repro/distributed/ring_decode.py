@@ -27,9 +27,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from .compat import shard_map
 
 from .context import current_context
 

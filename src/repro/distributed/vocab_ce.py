@@ -12,10 +12,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..distributed import current_context
-from .compat import shard_map
 
 
 def _local_ce_stats(x, w_local, labels, v_lo, v_hi, n_valid):

@@ -6,7 +6,13 @@ jax device state (mandated — smoke tests must keep seeing 1 device).
 
 from __future__ import annotations
 
-from ..distributed.compat import make_mesh
+import jax
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis Auto."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -21,10 +21,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..distributed import current_context
-from ..distributed.compat import shard_map
 from ..kernels import moe_gmm
 from .config import ModelConfig
 

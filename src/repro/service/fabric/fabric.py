@@ -37,6 +37,8 @@ import time
 from dataclasses import replace
 from typing import Optional
 
+import jax
+
 from ..observability import SUBMITTED, TraceSink, make_hop
 from ..priority import Priority
 from ..server import ServiceConfig, StratumService
@@ -92,10 +94,16 @@ class StratumFabric:
         the routing-key space remaps onto it (see ``ring.py``), so existing
         shards keep their cache/CSE locality."""
         with self._lock:
-            shard_id = f"shard-{next(self._shard_seq)}"
+            index = next(self._shard_seq)
+            shard_id = f"shard-{index}"
+            # one device per shard: on a multi-chip host shard i dispatches
+            # to device i (mod the device count); with one device every
+            # shard shares it
+            devices = jax.devices()
             svc = StratumService(
                 config=replace(self.config, shard_id=shard_id),
-                autostart=autostart)
+                autostart=autostart,
+                device=devices[index % len(devices)])
             self._shards[shard_id] = svc
         self.router.add_shard(shard_id, LocalTransport(svc, shard_id))
         return shard_id

@@ -35,11 +35,12 @@ from .autoscale import Autoscaler, AutoscalePolicy
 from .fabric import ProcStratumFabric
 from .frames import (FrameDecoder, FrameError, decode_control,
                      encode_control, write_frame)
-from .supervisor import ProcConfig, WorkerSupervisor
+from .supervisor import ProcConfig, WorkerPlatformError, WorkerSupervisor
 from .transport import ProcTransport
 
 __all__ = [
     "Autoscaler", "AutoscalePolicy", "FrameDecoder", "FrameError",
-    "ProcConfig", "ProcStratumFabric", "ProcTransport", "WorkerSupervisor",
+    "ProcConfig", "ProcStratumFabric", "ProcTransport",
+    "WorkerPlatformError", "WorkerSupervisor",
     "decode_control", "encode_control", "write_frame",
 ]

@@ -70,6 +70,10 @@ class ProcConfig:
     preload: tuple = ("repro.tabular",)
 
 
+class WorkerPlatformError(RuntimeError):
+    """A worker's JAX platform differs from the fabric's first worker's."""
+
+
 class _WorkerHandle:
     def __init__(self, shard_id: str, transport: ProcTransport,
                  config_blob: bytes):
@@ -87,6 +91,8 @@ class _WorkerHandle:
         self.failed = False
         self.handoff_event = threading.Event()
         self.handoff_entries: list = []
+        # why the handshake refused this worker (None = accepted)
+        self.refused: Optional[str] = None
 
 
 class _ProxyTelemetry:
@@ -183,6 +189,9 @@ class WorkerSupervisor:
         self.spawns = 0
         self.failures: list[tuple[str, str]] = []
         self.handoff_entries_shipped = 0
+        # (platform, device_kind) of the fabric's first worker: every later
+        # worker must report the same, or it is refused
+        self.device: Optional[tuple] = None
         self._listener = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         self._listener.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
         self._listener.bind((self.config.host, 0))
@@ -240,6 +249,9 @@ class WorkerSupervisor:
             raise TimeoutError(
                 f"worker for shard {shard_id!r} did not handshake within "
                 f"{cfg.spawn_timeout_s}s")
+        if handle.refused is not None:
+            self.destroy(shard_id)
+            raise WorkerPlatformError(handle.refused)
         return _ShardProxy(handle, self)
 
     # -- handshake path ------------------------------------------------------
@@ -267,6 +279,13 @@ class WorkerSupervisor:
             if handle is None or handle.failed:
                 sock.close()                # stranger (or zombie): refuse
                 return
+            refusal = self._check_device(shard_id, payload)
+            if refusal is not None:
+                handle.refused = refusal
+                handle.failed = True        # the monitor leaves it alone
+                sock.close()
+                handle.handshaken.set()     # wake spawn(), which raises
+                return
             if not handle.handshaken.is_set():
                 # first contact: ship the service config, then go live
                 write_frame(sock, encode_control(CONFIG, {
@@ -284,6 +303,23 @@ class WorkerSupervisor:
                 sock.close()
             except OSError:
                 pass
+
+    def _check_device(self, shard_id: str, hello: dict) -> Optional[str]:
+        """The first HELLO fixes the fabric's JAX platform; a later worker
+        whose platform differs (it lost the race for the chip and fell
+        back to the CPU) is refused.  Returns the refusal reason."""
+        device = (hello.get("platform"), hello.get("device_kind"))
+        with self._lock:
+            if self.device is None:
+                self.device = device
+                return None
+            expected = self.device
+        if device[0] == expected[0]:
+            return None
+        return (f"worker for shard {shard_id!r} runs on platform "
+                f"{device[0]!r} ({device[1]}), but the fabric's first "
+                f"worker runs on {expected[0]!r} ({expected[1]}): one "
+                f"process per chip")
 
     # -- control-plane events ------------------------------------------------
     def _on_control(self, handle: _WorkerHandle, kind: int,

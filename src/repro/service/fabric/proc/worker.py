@@ -5,7 +5,8 @@ StratumService` — fair queue, coalescer, cross-agent CSE, intermediate
 cache, compiled-plan cache — behind a framed socket to the supervisor.
 The protocol from the worker's seat:
 
-1. connect to ``--host:--port``, send ``HELLO {shard_id, pid}``;
+1. connect to ``--host:--port``, send ``HELLO {shard_id, pid, platform,
+   device_kind}`` (what JAX reports for this process's first device);
 2. receive ``CONFIG`` (pickled :class:`ServiceConfig` + proc options),
    build the service;
 3. loop: decode frames → JobEnvelope → ``service.submit`` → on future
@@ -87,8 +88,15 @@ class ShardWorker:
         return sock
 
     def _hello(self, sock: socket.socket) -> None:
+        # the platform JAX gave this process: on a one-chip host only the
+        # first worker gets the chip and a later one would silently run
+        # on the CPU — the supervisor refuses such a worker
+        import jax
+        device = jax.devices()[0]
         write_frame(sock, encode_control(
-            HELLO, {"shard_id": self.shard_id, "pid": os.getpid()}))
+            HELLO, {"shard_id": self.shard_id, "pid": os.getpid(),
+                    "platform": device.platform,
+                    "device_kind": device.device_kind}))
 
     def _await_config(self, sock: socket.socket,
                       decoder: FrameDecoder) -> list:

@@ -165,6 +165,8 @@ class FabricTelemetry:
                 r.get("speculative_hits", 0) for r in pc_rows)
             totals["plan_cache_compile_time_s"] = sum(
                 r.get("compile_time_s", 0.0) for r in pc_rows)
+            totals["plan_cache_runtime_failures"] = sum(
+                r.get("runtime_failures", 0) for r in pc_rows)
         # windowed throughput/attainment fabric-wide: counters sum, depth
         # maxes, percentiles recombine from each shard's capped samples
         win_rows = [s["windows"] for s in per_shard.values()

@@ -55,6 +55,7 @@ from ..core.api import ALL_FEATURES, Stratum
 from ..core.backends import make_backends
 from ..core.cache import IntermediateCache
 from ..core.fusion import PipelineBatch
+from ..core.placement import on_device
 from ..core.plan_cache import PlanCache
 from ..core.runtime import ExecutionError, ExecutionPreempted, Runtime
 from .coalesce import SuperBatch, coalesce, cross_agent_dedup, reachable_sigs
@@ -76,7 +77,6 @@ class ServiceConfig:
     platform: str = ""
     enable: Sequence[str] = ALL_FEATURES
     hardware_threads: int = 0
-    jit_cache_dir: Optional[str] = None
     # admission control
     max_queued_total: int = 1024
     max_queued_per_tenant: int = 256
@@ -179,12 +179,15 @@ class StratumService:
     runtime.  Thread-safe; one instance serves many concurrent agents."""
 
     def __init__(self, config: Optional[ServiceConfig] = None,
-                 autostart: bool = True, **overrides):
+                 autostart: bool = True, device=None, **overrides):
         if config is None:
             config = ServiceConfig(**overrides)
         elif overrides:
             raise ValueError("pass either config or keyword overrides")
         self.config = config
+        # the JAX device this service dispatches to: a fabric shard owns
+        # one device of a multi-chip host; None = the process default
+        self.device = device
         self.cache: Optional[IntermediateCache] = None
         if "cache" in config.enable:
             self.cache = IntermediateCache(
@@ -201,7 +204,8 @@ class StratumService:
             self.plan_cache = PlanCache(
                 capacity=config.plan_cache_entries,
                 compile_async=config.compile_async,
-                speculative_depth=config.speculative_depth)
+                speculative_depth=config.speculative_depth,
+                device=device)
         self._backends = make_backends(self.plan_cache,
                                        compiled=config.compiled_segments,
                                        batch_variants=config.batch_variants)
@@ -212,7 +216,6 @@ class StratumService:
             platform=config.platform,
             enable=config.enable,
             hardware_threads=config.hardware_threads,
-            jit_cache_dir=config.jit_cache_dir,
             cache=self.cache,
             compiled_segments=config.compiled_segments,
             plan_cache=self.plan_cache,
@@ -283,7 +286,8 @@ class StratumService:
             max_workers=self.config.n_executors,
             thread_name_prefix="stratum-exec")
         self._dispatcher = threading.Thread(
-            target=self._dispatch_loop, name="stratum-dispatch", daemon=True)
+            target=on_device(self.device, self._dispatch_loop),
+            name="stratum-dispatch", daemon=True)
         self._dispatcher.start()
         return self
 
@@ -565,7 +569,8 @@ class StratumService:
                     jobs.extend(more)
             with self._inflight_cond:
                 self._inflight_jobs += len(jobs)
-            self._pool.submit(self._execute_guarded, jobs)
+            self._pool.submit(on_device(self.device, self._execute_guarded),
+                              jobs)
 
     def _adjust_free_slots(self, delta: int) -> None:
         with self._free_slots_lock:
@@ -745,7 +750,8 @@ class StratumService:
                          preloaded=preloaded,
                          preempt_check=self._preempt_check_for(live, band),
                          sig_tenant=sig_tenant,
-                         backends=self._backends)
+                         backends=self._backends,
+                         device=self.device)
             results, run = rt.execute(sinks, plan, sel)
         except ExecutionPreempted as p:
             self._release_mem(need)
@@ -801,6 +807,8 @@ class StratumService:
                     plan_cache_misses=getattr(run, "plan_cache_misses", 0),
                     plan_cache_fallback_rounds=getattr(
                         run, "plan_cache_fallback_rounds", 0),
+                    segment_runtime_failures=getattr(
+                        run, "segment_runtime_failures", 0),
                     deadline_met=deadline_met)
                 self.traces.finish(job.trace)
                 trace_hops = job.trace.as_hops()

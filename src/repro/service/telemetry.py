@@ -324,7 +324,8 @@ class ServiceTelemetry:
             lines.append(
                 f"plan cache: {pc['entries']} compiled segment(s) "
                 f"hit_rate={pc['hit_rate']:.2f} "
-                f"(compiles {pc['compiles']}, evictions {pc['evictions']})")
+                f"(compiles {pc['compiles']}, evictions {pc['evictions']}, "
+                f"runtime failures {pc.get('runtime_failures', 0)})")
             if pc.get("async"):
                 lines.append(
                     f"compile lane: async={pc.get('async_compiles', 0)} "

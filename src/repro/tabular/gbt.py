@@ -157,20 +157,65 @@ def unpack(model: np.ndarray, F_expected: int):
 # jax ("native"-tier) implementation — one compiled program per shape/config
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("n_trees", "depth", "n_bins"))
+def _lookup(table, idx, dense: bool):
+    """``table[idx]`` for a small table: a one-hot select when ``dense``."""
+    if not dense:
+        return table[idx]
+    hit = idx[:, None] == jnp.arange(table.shape[0])
+    return jnp.sum(jnp.where(hit, table, 0), axis=1).astype(table.dtype)
+
+
+def _row_values(B, col, dense: bool):
+    """``B[i, col[i]]`` for every row i: a one-hot select when ``dense``."""
+    if not dense:
+        return B[jnp.arange(B.shape[0]), col]
+    hit = jnp.arange(B.shape[1]) == col[:, None]
+    return jnp.sum(jnp.where(hit, B, 0), axis=1)
+
+
+def _histograms(B, level_node, g, width: int, n_bins: int, dense: bool):
+    """Gradient sums and row counts per (node, feature, bin) of one tree
+    level, each ``(width, F, n_bins)``.
+
+    ``dense`` builds them as one ``(2·width × n)·(n × n_bins)`` matmul per
+    feature; otherwise as ONE flat segment_sum over (node, feature, bin)
+    ids — O(n·F) adds, no (n, F, bins) one-hot materialization, but a
+    scatter whose colliding adds a TPU serialises."""
+    n, F = B.shape
+    if dense:
+        node_oh = jax.nn.one_hot(level_node, width, dtype=jnp.float32)
+        L = jnp.concatenate([node_oh * g[:, None], node_oh], axis=1)
+
+        def per_feature(b):
+            return jnp.dot(L.T, jax.nn.one_hot(b, n_bins, dtype=jnp.float32),
+                           precision=jax.lax.Precision.HIGHEST)
+
+        h = jax.lax.map(per_feature, B.T).transpose(1, 0, 2)
+        return h[:width], h[width:]
+    feat_ids = jnp.arange(F, dtype=jnp.int32)[None, :]
+    # flat segment id: ((node·F) + f)·bins + bin
+    seg = ((level_node[:, None] * F + feat_ids) * n_bins + B).reshape(-1)
+    n_segs = width * F * n_bins
+    gf = jnp.broadcast_to(g[:, None], (n, F)).reshape(-1)
+    hist_g = jax.ops.segment_sum(gf, seg, num_segments=n_segs)
+    hist_c = jax.ops.segment_sum(jnp.ones_like(gf), seg, num_segments=n_segs)
+    return (hist_g.reshape(width, F, n_bins),
+            hist_c.reshape(width, F, n_bins))
+
+
+@partial(jax.jit, static_argnames=("n_trees", "depth", "n_bins", "dense"))
 def _fit_jax_binned(B, g0_y, base, lr, reg, n_trees: int, depth: int,
-                    n_bins: int):
+                    n_bins: int, dense: bool = False):
     """B: (n,F) int32 binned features; returns (feats, thrs, leaves).
 
-    Histograms via ONE flat segment_sum per level over (node, feature, bin)
-    ids — O(n·F) adds, no (n, F, bins) one-hot materialization."""
+    ``dense`` (the TPU's form) uses no scatter and no per-row gather:
+    histograms by matmul, node lookups by one-hot selects."""
     n, F = B.shape
     n_nodes = 2 ** depth - 1
     n_leaves = 2 ** depth
-    feat_ids = jnp.arange(F, dtype=jnp.int32)[None, :]     # (1, F)
 
     def tree_round(pred, _):
-        g = pred - g0_y                                   # (n,)
+        g = (pred - g0_y).astype(jnp.float32)             # (n,)
         node = jnp.zeros(n, dtype=jnp.int32)
         feats = jnp.zeros(n_nodes, dtype=jnp.int32)
         thrs = jnp.zeros(n_nodes, dtype=jnp.int32)
@@ -180,17 +225,8 @@ def _fit_jax_binned(B, g0_y, base, lr, reg, n_trees: int, depth: int,
             first = 2 ** d - 1
             width = 2 ** d
             level_node = jnp.clip(node - first, 0, width - 1)  # (n,)
-            # flat segment id: ((node·F) + f)·bins + bin
-            seg = ((level_node[:, None] * F + feat_ids) * n_bins
-                   + B).reshape(-1)                            # (n·F,)
-            n_segs = width * F * n_bins
-            gf = jnp.broadcast_to(g.astype(jnp.float32)[:, None],
-                                  (n, F)).reshape(-1)
-            hist_g = jax.ops.segment_sum(
-                gf, seg, num_segments=n_segs).reshape(width, F, n_bins)
-            hist_c = jax.ops.segment_sum(
-                jnp.ones_like(gf), seg,
-                num_segments=n_segs).reshape(width, F, n_bins)
+            hist_g, hist_c = _histograms(B, level_node, g, width, n_bins,
+                                         dense)
             cg = jnp.cumsum(hist_g, axis=-1)[..., :-1]
             cc = jnp.cumsum(hist_c, axis=-1)[..., :-1]
             g_tot = hist_g.sum(axis=-1, keepdims=True)
@@ -205,7 +241,8 @@ def _fit_jax_binned(B, g0_y, base, lr, reg, n_trees: int, depth: int,
             idx = first + jnp.arange(width)
             feats = feats.at[idx].set(bf)
             thrs = thrs.at[idx].set(bb)
-            go_right = (B[jnp.arange(n), feats[node]] > thrs[node])
+            go_right = (_row_values(B, _lookup(feats, node, dense), dense)
+                        > _lookup(thrs, node, dense))
             node = node * 2 + 1 + go_right.astype(jnp.int32)
             return node, feats, thrs
 
@@ -217,10 +254,13 @@ def _fit_jax_binned(B, g0_y, base, lr, reg, n_trees: int, depth: int,
 
         leaf_id = node - (2 ** depth - 1)
         Loh = jax.nn.one_hot(leaf_id, n_leaves, dtype=jnp.float32)
-        gs = Loh.T @ g.astype(jnp.float32)                 # (leaves,)
+        # full float32: a default-precision TPU matmul rounds the
+        # gradients to bf16
+        gs = jnp.dot(Loh.T, g,
+                     precision=jax.lax.Precision.HIGHEST)  # (leaves,)
         cs = Loh.sum(axis=0)
         leaf_vals = (-lr * gs / (cs + reg)).astype(pred.dtype)
-        pred = pred + leaf_vals[leaf_id]
+        pred = pred + _lookup(leaf_vals, leaf_id, dense)
         return pred, (feats, thrs, leaf_vals)
 
     pred0 = jnp.full((n,), base, dtype=jnp.float64
@@ -232,7 +272,8 @@ def _fit_jax_binned(B, g0_y, base, lr, reg, n_trees: int, depth: int,
 
 def fit_jax(X: np.ndarray, y: np.ndarray, *, n_trees: int = 30,
             depth: int = 3, lr: float = 0.1, reg: float = 1.0,
-            subsample: float = 1.0, seed: int = 0) -> np.ndarray:
+            subsample: float = 1.0, seed: int = 0,
+            dense: bool = False) -> np.ndarray:
     # binning on host (cheap, one pass), training compiled
     bins = make_bins(X)
     B = bin_data(X, bins)
@@ -247,33 +288,36 @@ def fit_jax(X: np.ndarray, y: np.ndarray, *, n_trees: int = 30,
         B_fit, y_fit = B, y
     feats, thrs, leaves = _fit_jax_binned(
         jnp.asarray(B_fit), jnp.asarray(y_fit, dtype=jnp.float32),
-        base, lr, reg, n_trees, depth, N_BINS)
+        base, lr, reg, n_trees, depth, N_BINS, dense)
     return pack(base, bins, np.asarray(feats).reshape(n_trees, -1),
                 np.asarray(thrs).reshape(n_trees, -1),
                 np.asarray(leaves, dtype=np.float64).reshape(n_trees, -1),
                 depth)
 
 
-@partial(jax.jit, static_argnames=("depth",))
-def _predict_jax(B, feats, thrs, leaves, base, depth: int):
+@partial(jax.jit, static_argnames=("depth", "dense"))
+def _predict_jax(B, feats, thrs, leaves, base, depth: int,
+                 dense: bool = False):
     n = B.shape[0]
 
     def one_tree(carry, tree):
         f, th, lv = tree
         node = jnp.zeros(n, dtype=jnp.int32)
         for _ in range(depth):
-            go_right = B[jnp.arange(n), f[node]] > th[node]
+            go_right = (_row_values(B, _lookup(f, node, dense), dense)
+                        > _lookup(th, node, dense))
             node = node * 2 + 1 + go_right.astype(jnp.int32)
-        return carry + lv[node - (2 ** depth - 1)], None
+        return carry + _lookup(lv, node - (2 ** depth - 1), dense), None
 
     out, _ = jax.lax.scan(one_tree, jnp.full((n,), base, dtype=leaves.dtype),
                           (feats, thrs, leaves))
     return out
 
 
-def predict_jax(model: np.ndarray, X: np.ndarray) -> np.ndarray:
+def predict_jax(model: np.ndarray, X: np.ndarray, *,
+                dense: bool = False) -> np.ndarray:
     base, bins, feats, thrs, leaves, depth = unpack(model, X.shape[1])
     B = bin_data(X, bins)
     out = _predict_jax(jnp.asarray(B), jnp.asarray(feats), jnp.asarray(thrs),
-                       jnp.asarray(leaves), base, depth)
+                       jnp.asarray(leaves), base, depth, dense)
     return np.asarray(out)

@@ -30,6 +30,11 @@ from . import gbt
 
 F64, F32 = "float64", "float32"
 
+# full float32 matmuls: on the TPU a float32 matmul at default precision
+# runs in bf16 passes, which moves normal-equation fits and predictions
+# over a million rows far from the float64 python tier
+_HI = jax.lax.Precision.HIGHEST
+
 
 def _validate(X):
     """sklearn-style check_array pass: full scan + dtype copy."""
@@ -586,10 +591,20 @@ def ridge_py(op, ins):
 
 @partial(jax.jit)
 def _ridge_solve(X, y, alpha):
+    """The python tier's penalised normal equations, solved by QR in
+    float32: ``Xb = QR`` once, then ``[R; sqrt(alpha) I]`` (d+d rows) per
+    alpha — under a vmap over alphas the tall QR is not batched.  Forming
+    ``XbᵀXb`` squares the condition number, and with price-scale target
+    encodings beside one-hot columns collinear with the bias it reaches
+    ~1e16: a float32 Cholesky of it returns NaN."""
     Xb = jnp.concatenate([X, jnp.ones((X.shape[0], 1), X.dtype)], axis=1)
-    XtX = Xb.T @ Xb + alpha * jnp.eye(Xb.shape[1], dtype=X.dtype)
-    Xty = Xb.T @ y
-    return jax.scipy.linalg.solve(XtX, Xty, assume_a="pos")
+    d = Xb.shape[1]
+    Q, R = jnp.linalg.qr(Xb)
+    qty = jnp.dot(Q.T, y, precision=_HI)
+    Q2, R2 = jnp.linalg.qr(jnp.concatenate(
+        [R, jnp.sqrt(alpha) * jnp.eye(d, dtype=X.dtype)], axis=0))
+    rhs = jnp.dot(Q2[:d].T, qty, precision=_HI)
+    return jax.scipy.linalg.solve_triangular(R2, rhs, lower=False)
 
 
 @register_impl("ridge_fit", "jax", vmappable=True, traceable=True)
@@ -645,13 +660,13 @@ def _enet_fista(X, y, alpha, l1r, iters: int):
     yc = y - ym
     l1 = alpha * l1r * n
     l2 = alpha * (1 - l1r) * n
-    G = Xs.T @ Xs
+    G = jnp.dot(Xs.T, Xs, precision=_HI)
     L = jnp.linalg.norm(G, ord=2) + l2 + 1e-6   # Lipschitz bound
-    Xty = Xs.T @ yc
+    Xty = jnp.dot(Xs.T, yc, precision=_HI)
 
     def step(carry, _):
         w, z, t = carry
-        grad = G @ z - Xty + l2 * z
+        grad = jnp.dot(G, z, precision=_HI) - Xty + l2 * z
         u = z - grad / L
         w_new = jnp.sign(u) * jnp.maximum(jnp.abs(u) - l1 / L, 0)
         t_new = (1 + jnp.sqrt(1 + 4 * t * t)) / 2
@@ -662,7 +677,7 @@ def _enet_fista(X, y, alpha, l1r, iters: int):
                                        jnp.zeros(d, X.dtype),
                                        jnp.asarray(1.0, X.dtype)),
                                 None, length=iters)
-    bias = ym - (mu / sd) @ w
+    bias = ym - jnp.dot(mu / sd, w, precision=_HI)
     return jnp.concatenate([w / sd, bias[None]])
 
 
@@ -693,14 +708,27 @@ def gbt_py(op, ins):
                           subsample=s["subsample"], seed=op.seed or 0),)
 
 
-@register_impl("gbt_fit", "jax")
-def gbt_jx(op, ins):
+# Two forms of the jax GBT, one per platform: the TPU serialises scatter-adds
+# with colliding ids and per-row gathers, so it grows the trees by matmul
+# histograms and one-hot lookups; the CPU fits 4.8x faster in the scatter form.
+def _gbt_fit_jax(op, ins, dense: bool):
     X, y = np.asarray(ins[0], dtype=np.float64), \
         np.asarray(ins[1], dtype=np.float64).ravel()
     s = op.spec
     return (gbt.fit_jax(X, y, n_trees=s["n_trees"], depth=s["depth"],
                         lr=s["learning_rate"], reg=s["reg"],
-                        subsample=s["subsample"], seed=op.seed or 0),)
+                        subsample=s["subsample"], seed=op.seed or 0,
+                        dense=dense),)
+
+
+@register_impl("gbt_fit", "jax", platforms=("cpu", "gpu"))
+def gbt_jx(op, ins):
+    return _gbt_fit_jax(op, ins, dense=False)
+
+
+@register_impl("gbt_fit", "jax", platforms=("tpu",))
+def gbt_jx_dense(op, ins):
+    return _gbt_fit_jax(op, ins, dense=True)
 
 
 @register_meta("gbt_fit")
@@ -726,7 +754,7 @@ def linpred_py(op, ins):
 def linpred_jax(op, ins):
     w = jnp.asarray(ins[0], dtype=jnp.float32)
     X = jnp.asarray(ins[1], dtype=jnp.float32)
-    return (X @ w[:-1] + w[-1],)
+    return (jnp.dot(X, w[:-1], precision=_HI) + w[-1],)
 
 
 @register_meta("linear_predict")
@@ -743,10 +771,17 @@ def gbtpred_py(op, ins):
                                                              dtype=np.float64)),)
 
 
-@register_impl("gbt_predict", "jax")
+@register_impl("gbt_predict", "jax", platforms=("cpu", "gpu"))
 def gbtpred_jax(op, ins):
     return (gbt.predict_jax(np.asarray(ins[0]),
                             np.asarray(ins[1], dtype=np.float64)),)
+
+
+@register_impl("gbt_predict", "jax", platforms=("tpu",))
+def gbtpred_jax_dense(op, ins):
+    return (gbt.predict_jax(np.asarray(ins[0]),
+                            np.asarray(ins[1], dtype=np.float64),
+                            dense=True),)
 
 
 @register_meta("gbt_predict")

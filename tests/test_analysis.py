@@ -66,7 +66,7 @@ sys_path = %r
 import sys; sys.path.insert(0, sys_path)
 from repro.launch.hlo_cost import analyze
 from repro.launch.mesh import make_debug_mesh
-from repro.distributed.compat import shard_map
+from jax import shard_map
 mesh = make_debug_mesh((1, 4), ("data", "model"))
 
 @partial(shard_map, mesh=mesh, in_specs=P(None, "model"),

@@ -189,6 +189,42 @@ def test_uncompilable_segment_falls_back_to_per_op(monkeypatch):
                                float(np.asarray(r_off)), rtol=1e-6)
 
 
+def test_compiled_segment_runtime_failure_is_counted(monkeypatch):
+    """A compiled program that raises when called (a compile the device
+    refuses, memory exhaustion) re-runs per-op with correct results, and
+    the failure is counted in the run report and the plan cache."""
+    from repro.core.backends import JaxSegmentBackend
+
+    def refused(*args, **kwargs):
+        raise RuntimeError("RESOURCE_EXHAUSTED: forced for the test")
+
+    real = JaxSegmentBackend._build_probed
+
+    def build_refused(self, *args, **kwargs):
+        return refused if real(self, *args, **kwargs) is not None else None
+
+    monkeypatch.setattr(JaxSegmentBackend, "_build_probed", build_refused)
+    on, off = _compiled_sessions()
+    sink = _variant_sink(alpha=2.0)
+    r_on, rep_on = on.run(sink)
+    r_off, _ = off.run(sink)
+    np.testing.assert_allclose(float(np.asarray(r_on)),
+                               float(np.asarray(r_off)), rtol=1e-6)
+    assert rep_on.run.segment_runtime_failures >= 1
+    assert "jax-seg" not in rep_on.run.per_backend
+    snap = on.plan_cache.snapshot()
+    assert snap["runtime_failures"] == rep_on.run.segment_runtime_failures
+    assert snap["warm_failures"] == 0 and snap["uncompilable"] == 0
+
+
+def test_compiled_segments_report_no_failures_when_healthy():
+    on, _ = _compiled_sessions()
+    _, rep = on.run(_variant_sink(alpha=2.0))
+    assert rep.run.per_backend.get("jax-seg", 0) > 0
+    assert rep.run.segment_runtime_failures == 0
+    assert on.plan_cache.snapshot()["runtime_failures"] == 0
+
+
 # ---------------------------------------------------------------------------
 # segment-boundary preemption: salvage exactness
 # ---------------------------------------------------------------------------

@@ -203,3 +203,35 @@ def test_runtime_cache_hits_are_exact(tmp_path):
     assert rep2.run.ops_from_cache > 0
     np.testing.assert_allclose(np.asarray(r1, dtype=np.float64),
                                np.asarray(r2, dtype=np.float64))
+
+
+# ---------------------------------------------------------------------------
+# persistent compilation cache placement
+# ---------------------------------------------------------------------------
+
+def test_compilation_cache_env_setting_is_left_alone(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX reads it and no code path
+    of the session or the service places the cache itself."""
+    import jax
+    from repro.core.api import resolve_compilation_cache
+    from repro.service import StratumService
+    calls = []
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a, **k: calls.append(a))
+    assert resolve_compilation_cache() is None
+    Stratum()
+    StratumService(autostart=False)
+    assert calls == []
+
+
+def test_compilation_cache_defaults_to_the_checkout(monkeypatch):
+    import jax
+    from pathlib import Path
+    from repro.core.api import JAX_CACHE_DIR, resolve_compilation_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    checkout = Path(__file__).resolve().parents[1]
+    assert JAX_CACHE_DIR == str(checkout / ".jax_cache")
+    Stratum()
+    assert jax.config.jax_compilation_cache_dir == JAX_CACHE_DIR
+    assert resolve_compilation_cache() == JAX_CACHE_DIR

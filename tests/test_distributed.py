@@ -1,9 +1,5 @@
 """Distributed correctness tests — run in subprocesses with a forced
-8-device host platform (the main test process must keep 1 device).
-
-``repro.distributed.compat`` bridges the jax version gap (shard_map /
-make_mesh spellings), so these run on both modern jax and the 0.4.37
-floor."""
+8-device host platform (the main test process must keep 1 device)."""
 
 import json
 import os
@@ -149,7 +145,7 @@ def test_compressed_psum_matches_psum():
     rng = np.random.default_rng(3)
     g = jnp.asarray(rng.normal(size=(8, 512)), jnp.float32)
 
-    from repro.distributed.compat import shard_map
+    from jax import shard_map
 
     @partial(shard_map, mesh=mesh, in_specs=P(("data", "model")),
              out_specs=P(("data", "model")), check_vma=False)

@@ -624,3 +624,65 @@ def test_remaining_deadline_shrinks_at_reencode_on_failover():
         assert "p" in results and report.deadline_met is True
     finally:
         fab.stop()
+
+
+def test_fabric_shard_i_dispatches_to_device_i():
+    """On a host with several devices (four virtual CPU devices here, four
+    chips on a v5e host) shard i's compiled segments run on device i."""
+    import json
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    code = textwrap.dedent("""
+    import json, jax
+    import repro.tabular as T
+    from repro.core import PipelineBatch
+    from repro.core.backends import JaxSegmentBackend
+    from repro.service.fabric import ConsistentHashRing, StratumFabric
+    # record, for every compiled segment, the runtime's device and the
+    # devices its outputs landed on
+    placed = []
+    commit = JaxSegmentBackend._commit
+    def recording_commit(self, rt, compute, outs, selection, report):
+        placed.append([str(rt.device), sorted({
+            str(d) for out in outs for v in out if hasattr(v, "devices")
+            for d in v.devices()})])
+        return commit(self, rt, compute, outs, selection, report)
+    JaxSegmentBackend._commit = recording_commit
+    fab = StratumFabric(n_shards=4, n_executors=1)
+    try:
+        ring = ConsistentHashRing(fab.shard_ids(), vnodes=64)
+        keys, k = {}, 0
+        while len(keys) < 4:
+            keys.setdefault(ring.route(f"k{k}"), f"k{k}")
+            k += 1
+        out = {}
+        for sid, key in sorted(keys.items()):
+            x = T.read("uk_housing", 500, seed=0)
+            sink = T.scale(T.impute(T.project(x, [10, 11, 12])))
+            res, rep = fab.session("t").submit(
+                PipelineBatch([sink], ["p"]), affinity=key).result(120)
+            out[sid] = [rep.shard_id, rep.per_backend.get("jax-seg", 0),
+                        str(fab._shards_snapshot()[sid].device)]
+        print(json.dumps({"devices": [str(d) for d in jax.devices()],
+                          "out": out, "placed": placed}))
+    finally:
+        fab.stop()
+    """)
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env = dict(os.environ, PYTHONPATH=src,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    got = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert len(got["devices"]) == 4
+    for sid, (ran_on, n_seg, device) in got["out"].items():
+        assert ran_on == sid and n_seg > 0
+        assert device == got["devices"][int(sid.split("-")[1])]
+    # every compiled segment's outputs sit on its shard's device, and the
+    # four shards used all four devices
+    assert all(outs == [device] for device, outs in got["placed"])
+    assert {device for device, _ in got["placed"]} == set(got["devices"])

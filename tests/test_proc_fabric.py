@@ -549,3 +549,69 @@ def test_sigkill_mid_dispatch_trace_survives_and_replays(tmp_path):
     summary = replay.summarize(timelines)
     assert summary["failovers"] >= 1
     assert summary["outcomes"].get(COMPLETED, 0) >= 6
+
+
+class _HelloOnlyWorker:
+    """Stands in for a worker process: dials the supervisor and sends one
+    HELLO naming a platform (tpu for shard "s0", cpu for every other)."""
+
+    def __init__(self, argv, env=None, start_new_session=False):
+        import socket
+        import threading
+        from repro.service.fabric.proc.frames import write_frame
+        shard_id = argv[argv.index("--shard-id") + 1]
+        port = int(argv[argv.index("--port") + 1])
+        platform = "tpu" if shard_id == "s0" else "cpu"
+        hello = {"shard_id": shard_id, "pid": 0, "platform": platform,
+                 "device_kind": "TPU v5 lite" if platform == "tpu"
+                 else "cpu"}
+        self.pid = None
+        self.returncode = None
+        self._sock = socket.create_connection(("127.0.0.1", port))
+        threading.Thread(target=write_frame, daemon=True, args=(
+            self._sock, encode_control(HELLO, hello))).start()
+
+    def poll(self):
+        return self.returncode
+
+    def kill(self):
+        self.returncode = -9
+
+    def terminate(self):
+        self.returncode = -15
+
+    def wait(self, timeout=None):
+        if self.returncode is None:
+            self.returncode = 0
+        self._sock.close()
+        return self.returncode
+
+
+def test_supervisor_refuses_a_worker_on_another_platform(monkeypatch):
+    """One process per chip: a worker that lost the chip to the fabric's
+    first worker reports the CPU in its HELLO, and spawn raises instead
+    of carrying on with a CPU shard."""
+    from repro.service import ServiceConfig
+    from repro.service.fabric.proc import (WorkerPlatformError,
+                                           WorkerSupervisor)
+    from repro.service.fabric.proc import supervisor as sup_mod
+    monkeypatch.setattr(sup_mod.subprocess, "Popen", _HelloOnlyWorker)
+    sup = WorkerSupervisor(ProcConfig(spawn_timeout_s=10.0,
+                                      heartbeat_timeout_s=60.0))
+    try:
+        sup.spawn("s0", ServiceConfig())
+        assert sup.device == ("tpu", "TPU v5 lite")
+        with pytest.raises(WorkerPlatformError, match="'cpu'"):
+            sup.spawn("s1", ServiceConfig())
+        assert set(sup.live_workers()) == {"s0"}
+    finally:
+        sup.shutdown()
+
+
+def test_worker_hello_reports_its_jax_platform():
+    """A real worker's HELLO names the platform JAX gave it; on the CPU
+    the fabric's workers all agree and the fabric runs."""
+    with _proc_fabric(n_shards=2) as fab:
+        res, _ = fab.session("t").submit(_batch()).result(timeout=120)
+        assert float(res["p"]) == float(res["p"])      # not NaN
+        assert fab.supervisor.device[0] == "cpu"

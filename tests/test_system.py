@@ -120,3 +120,24 @@ def test_agent_search_improves_over_drafts():
                               for i in range(len(specs))])
     assert agent.best() is not None
     assert np.isfinite(agent.best().score)
+
+
+def test_chip_smoke_refuses_to_run_without_a_tpu(tmp_path):
+    """chip_smoke.py exits non-zero and prints no result line when JAX
+    finds no TPU, and when it stands alone without the repository."""
+    import os
+    import shutil
+    import subprocess
+    import sys
+    from pathlib import Path
+    script = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    alone = tmp_path / "chip_smoke.py"
+    shutil.copy(script, alone)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    for path in (script, alone):
+        proc = subprocess.run([sys.executable, str(path), "--rows", "2000"],
+                              env=env, capture_output=True, text=True,
+                              timeout=300, cwd=tmp_path)
+        assert proc.returncode != 0
+        assert '"ok"' not in proc.stdout
+        assert "no TPU" in proc.stderr

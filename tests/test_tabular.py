@@ -77,6 +77,32 @@ def test_ridge_tiers_and_quality():
     assert 1 - ss_res / ss_tot > 0.3     # learns something real
 
 
+def test_ridge_jax_tier_scores_the_manual_features_at_scale():
+    """The §6 manual features put price-scale target encodings beside
+    one-hot columns that sum to the bias column: ``XbᵀXb`` reaches a
+    condition number near 1e16, and at 100,000 rows a float32 Cholesky of
+    it returned NaN in some folds.  The jax tier's QR solve scores as the
+    float64 python tier does."""
+    from repro.agents.aide import PipelineSpec
+    from repro.core import Stratum
+    from repro.core.lowering import lower
+    from repro.core.metadata import collect_metadata
+    from repro.core.runtime import Runtime
+    from repro.core.scheduler import SchedulerConfig, plan as make_plan
+    from repro.core.selection import SelectionConfig, select
+    sink = PipelineSpec(preproc="manual", model="ridge", n_rows=100_000,
+                        cv_k=3, seed=7).build()
+    jx, rep = Stratum().run(sink)
+    assert rep.run.per_backend.get("python", 0) > 0
+    sinks = lower([sink])
+    collect_metadata(sinks)
+    sel = select(sinks, SelectionConfig(allowed_backends=("python",)))
+    plan = make_plan(sinks, sel, SchedulerConfig(enable_inter_op=False))
+    py = Runtime(cache=None, parallel=False).execute(sinks, plan, sel)[0][0]
+    np.testing.assert_allclose(float(np.asarray(jx)), float(np.asarray(py)),
+                               rtol=1e-4)
+
+
 def test_elasticnet_tiers_agree_in_loss():
     rng = np.random.default_rng(0)
     X = rng.normal(size=(300, 8))
@@ -107,6 +133,47 @@ def test_gbt_numpy_vs_jax_same_trees():
     np.testing.assert_allclose(p_np, p_jx, rtol=1e-3, atol=1e-2)
     # and it learns
     assert np.mean((p_np - y) ** 2) < np.var(y) * 0.4
+
+
+@pytest.mark.parametrize("depth", [2, 3])
+def test_gbt_dense_form_grows_the_same_trees(depth):
+    """The TPU's scatter-free form (matmul histograms, one-hot lookups)
+    grows the trees and predictions of the segment_sum/gather form."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(2)
+    X = rng.normal(size=(3000, 9))
+    y = X[:, 0] * 2 + np.sin(X[:, 1]) + 0.1 * rng.normal(size=3000)
+    B = jnp.asarray(gbt.bin_data(X, gbt.make_bins(X)))
+    args = (B, jnp.asarray(y, jnp.float32), float(y.mean()), 0.1, 1.0, 12,
+            depth, gbt.N_BINS)
+    sparse, dense = (gbt._fit_jax_binned(*args, dense=d)
+                     for d in (False, True))
+    np.testing.assert_array_equal(sparse[0], dense[0])
+    np.testing.assert_array_equal(sparse[1], dense[1])
+    np.testing.assert_allclose(sparse[2], dense[2], rtol=1e-5, atol=1e-7)
+    preds = [gbt._predict_jax(B, *sparse, float(y.mean()), depth, dense=d)
+             for d in (False, True)]
+    np.testing.assert_allclose(preds[0], preds[1], rtol=1e-6)
+
+
+@pytest.mark.parametrize("platform,form", [
+    ("tpu", "gbt_jx_dense"), ("cpu", "gbt_jx"), ("gpu", "gbt_jx")])
+def test_selection_picks_the_gbt_form_of_the_platform(platform, form):
+    """The jax GBT form is an impl per platform, so the selection pass, and
+    a ``platform`` set in its config, decides which form runs."""
+    from repro.agents.aide import PipelineSpec
+    from repro.core.lowering import lower
+    from repro.core.metadata import collect_metadata
+    from repro.core.selection import SelectionConfig, select
+    sinks = lower([PipelineSpec(preproc="manual", model="gbt_xgboost",
+                                n_rows=2000, cv_k=2, seed=0).build()])
+    collect_metadata(sinks)
+    sel = select(sinks, SelectionConfig(platform=platform,
+                                        allowed_backends=("jax",)))
+    chosen = {impl.fn.__name__ for impl in sel.values()
+              if impl.op_name in ("gbt_fit", "gbt_predict")}
+    pred = "gbtpred_jax_dense" if platform == "tpu" else "gbtpred_jax"
+    assert chosen == {form, pred}
 
 
 def test_kfold_split_partition_properties():
