@@ -71,6 +71,7 @@ visible in telemetry.
 
 from __future__ import annotations
 
+import hashlib
 import threading
 from collections import OrderedDict
 from typing import Optional, Sequence
@@ -79,6 +80,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import spans
 from ..dag import LazyOp, tunable_fields
 from ..plan_cache import PlanCache
 from .base import ExecutionBackend
@@ -260,21 +262,29 @@ class JaxSegmentBackend(ExecutionBackend):
 
     def _program_of(self, compute, selection):
         """What defines the program of a compute set, for every path that
-        builds, compiles or looks one up: ``(key, in_specs, ext_keys,
-        hoists, hoist_vals)``.  The key holds the structure of every
-        traced op, the cut (which inputs are external) and the exact impl
-        chosen (fidelity annotations can swap impls between structurally
-        identical plans)."""
+        builds, compiles or looks one up: ``(key, name, in_specs,
+        ext_keys, hoists, hoist_vals)``.  The key holds the structure of
+        every traced op, the cut (which inputs are external) and the exact
+        impl chosen (fidelity annotations can swap impls between
+        structurally identical plans).  The name, ``seg_<digest>``, is the
+        same program's in every process: a content hash of the structure,
+        the cut and each impl's op, tier and fidelity, never an object's
+        id, so device events, ``stratum.segment.run`` spans and JAX's
+        persistent compilation cache all see one name."""
         in_specs, ext_keys = self._wiring(compute)
         hoists = tuple(tuple(sorted(tunable_fields(op.op_name)
                                     & set(op.spec))) for op in compute)
         ssigs = tuple(op.structural_signature for op in compute)
-        impl_ids = tuple(id(selection[op.signature]) for op in compute)
-        key = (self._key_tag, ssigs, in_specs, impl_ids)
+        impls = [selection[op.signature] for op in compute]
+        key = (self._key_tag, ssigs, in_specs, tuple(map(id, impls)))
+        digest = hashlib.blake2b(repr((
+            self._key_tag, ssigs, in_specs,
+            tuple((i.op_name, i.backend, i.fidelity) for i in impls),
+        )).encode(), digest_size=8).hexdigest()
         hoist_vals = tuple(op.spec[f]
                            for op, fs in zip(compute, hoists)
                            for f in fs)
-        return key, in_specs, ext_keys, hoists, hoist_vals
+        return key, "seg_" + digest, in_specs, ext_keys, hoists, hoist_vals
 
     @staticmethod
     def _sources(compute, selection):
@@ -300,12 +310,12 @@ class JaxSegmentBackend(ExecutionBackend):
         if not compute or any(op.signature not in selection
                               for op in compute):
             return None
-        key, in_specs, ext_keys, hoists, hoist_vals = self._program_of(
-            compute, selection)
+        key, name, in_specs, ext_keys, hoists, hoist_vals = \
+            self._program_of(compute, selection)
         ref_by_key = {r.signature: r for op in compute for r in op.inputs
                       if r.op.signature not in produced}
         seg_fn, jitted = self._build(*self._sources(compute, selection),
-                                     in_specs, hoists, ())
+                                     in_specs, hoists, (), name)
         return (key, seg_fn, jitted, [ref_by_key[k] for k in ext_keys],
                 hoist_vals)
 
@@ -377,13 +387,15 @@ class JaxSegmentBackend(ExecutionBackend):
     # ------------------------------------------------------------------
     def _run_compiled(self, rt, segment, compute, selection,
                       report) -> None:
-        key, in_specs, ext_keys, hoists, hoist_vals = self._program_of(
-            compute, selection)
+        key, name, in_specs, ext_keys, hoists, hoist_vals = \
+            self._program_of(compute, selection)
+        spans.annotate(program=name)
         if self._is_uncompilable(key):
             self._fallback(rt, segment, compute, selection, report)
             return
         with rt._lock:
             ext_vals = tuple(rt._values[k] for k in ext_keys)
+        rt._note_crossing("jax", ext_vals, report)
         if self.plan_cache.executor is not None:
             self._note_ext(ext_keys, ext_vals)
         compiled = self.plan_cache.get(key)
@@ -403,14 +415,14 @@ class JaxSegmentBackend(ExecutionBackend):
                 # never the submitted DAG.
                 specs = tuple(self._aval_of(v) for v in ext_vals)
                 ex.submit(key, self._make_job(
-                    key, protos, impl_fns, in_specs, hoists, groups,
+                    key, name, protos, impl_fns, in_specs, hoists, groups,
                     specs, hoist_vals, speculative=False))
                 with rt._lock:
                     report.plan_cache_fallback_rounds += 1
                 self._fallback(rt, segment, compute, selection, report)
                 return
             compiled = self._build_probed(
-                key, protos, impl_fns, in_specs, hoists, groups,
+                key, name, protos, impl_fns, in_specs, hoists, groups,
                 ext_vals, hoist_vals)
             if compiled is None:
                 # per-op reproduces any precise error
@@ -418,7 +430,8 @@ class JaxSegmentBackend(ExecutionBackend):
                 return
             self.plan_cache.put(key, compiled)
         try:
-            outs = compiled(ext_vals, hoist_vals)
+            with spans.span("stratum.segment.run", program=name):
+                outs = compiled(ext_vals, hoist_vals)
         except Exception:  # noqa: BLE001 — XLA compile or runtime failure
             # possibly transient (e.g. resource exhaustion): run per-op
             # this round WITHOUT forgetting the compiled program — tracing
@@ -433,8 +446,8 @@ class JaxSegmentBackend(ExecutionBackend):
             return
         self._commit(rt, compute, outs, selection, report)
 
-    def _make_job(self, key, protos, impl_fns, in_specs, hoists, groups,
-                  ext_specs, hoist_vals, speculative: bool):
+    def _make_job(self, key, name, protos, impl_fns, in_specs, hoists,
+                  groups, ext_specs, hoist_vals, speculative: bool):
         """Background compile closure: probe → build → warm-call on
         zero-filled inputs → publish.  A runtime failure of the warm call
         on zeros (value-dependent, e.g. a singular solve) does not block
@@ -444,7 +457,7 @@ class JaxSegmentBackend(ExecutionBackend):
         def job():
             zeros = self._zeros(ext_specs)
             jitted = self._build_probed(
-                key, protos, impl_fns, in_specs, hoists, groups,
+                key, name, protos, impl_fns, in_specs, hoists, groups,
                 zeros, hoist_vals)
             if jitted is None:
                 return           # marked uncompilable; demand runs per-op
@@ -455,7 +468,7 @@ class JaxSegmentBackend(ExecutionBackend):
             self.plan_cache.put(key, jitted, speculative=speculative)
         return job
 
-    def _build_probed(self, key, protos, impl_fns, in_specs, hoists,
+    def _build_probed(self, key, name, protos, impl_fns, in_specs, hoists,
                       groups, ext_example, hoist_example):
         """Build + abstract-trace probe, batched first.  A batched build
         whose probe fails (non-uniform member shapes, an impl vmap can't
@@ -465,7 +478,7 @@ class JaxSegmentBackend(ExecutionBackend):
         compile it precedes."""
         for gs in ((groups, ()) if groups else ((),)):
             seg_fn, jitted = self._build(protos, impl_fns, in_specs,
-                                         hoists, gs)
+                                         hoists, gs, name)
             if not gs and self._is_preverified(key):
                 # the static analyzer already eval_shape-probed this exact
                 # build (analysis feasibility pass) — skip the re-probe.
@@ -525,10 +538,11 @@ class JaxSegmentBackend(ExecutionBackend):
         return tuple(groups)
 
     # ------------------------------------------------------------------
-    def _build(self, protos, impl_fns, in_specs, hoists, groups=()):
+    def _build(self, protos, impl_fns, in_specs, hoists, groups, name):
         """Returns ``(seg_fn, jitted)`` — the raw traceable function (for
         the abstract-trace probe) and its jit wrapper (what the plan
-        cache stores).  Takes proxies + impl functions, never LazyOps:
+        cache stores), the function named ``name`` so its program is
+        ``jit_<name>``.  Takes proxies + impl functions, never LazyOps:
         background compile jobs must not pin submitted DAGs.
 
         With ``groups``, each variant group becomes ONE ``jax.vmap`` call:
@@ -607,6 +621,7 @@ class JaxSegmentBackend(ExecutionBackend):
                     run_group(gi, ext_vals, hoist_vals, outs)
             return tuple(outs)
 
+        seg_fn.__name__ = seg_fn.__qualname__ = name
         return seg_fn, jax.jit(seg_fn)
 
     # -- speculative warm-up -------------------------------------------
@@ -640,8 +655,8 @@ class JaxSegmentBackend(ExecutionBackend):
                 produced.add(sig)
         if not compute:
             return "empty"
-        key, in_specs, ext_keys, hoists, hoist_vals = self._program_of(
-            compute, selection)
+        key, name, in_specs, ext_keys, hoists, hoist_vals = \
+            self._program_of(compute, selection)
         if self._is_uncompilable(key):
             return "uncompilable"
         if key in self.plan_cache:
@@ -669,7 +684,7 @@ class JaxSegmentBackend(ExecutionBackend):
             if self.batch_variants else ()
         protos, impl_fns = self._sources(compute, selection)
         ok = ex.submit(key, self._make_job(
-            key, protos, impl_fns, in_specs, hoists, groups,
+            key, name, protos, impl_fns, in_specs, hoists, groups,
             tuple(specs), hoist_vals, speculative=True), speculative=True)
         return "enqueued" if ok else "rejected"
 
@@ -682,12 +697,9 @@ class JaxSegmentBackend(ExecutionBackend):
                     op, ValueError(f"impl returned {len(out)} outputs, "
                                    f"declared {op.n_outputs}"))
             rt._store(op, out)
-            sig = op.signature
             with rt._lock:
                 report.ops_executed += 1
                 report.per_backend["jax-seg"] = \
                     report.per_backend.get("jax-seg", 0) + 1
-                report.sig_source[sig] = "jax-seg"
-            if (rt.cache is not None and op.cacheable
-                    and sig in rt.cache_candidates):
-                rt.cache.put(sig, out, tenant=rt.sig_tenant.get(sig))
+                report.sig_source[op.signature] = "jax-seg"
+            rt._cache_put(op, out)

@@ -34,6 +34,7 @@ benchmarks (they bypass the optimizer entirely).
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from concurrent.futures import (FIRST_COMPLETED, ThreadPoolExecutor,
@@ -41,7 +42,11 @@ from concurrent.futures import (FIRST_COMPLETED, ThreadPoolExecutor,
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional, Sequence
 
-from .cache import IntermediateCache
+import jax
+import numpy as np
+
+from . import spans
+from .cache import IntermediateCache, _nbytes
 from .dag import CONST, LazyOp, LazyRef
 from .placement import on_device
 from .plan_cache import PlanCache
@@ -72,6 +77,32 @@ class RunReport:
     # the device, memory exhaustion, a value-dependent failure) and were
     # re-run per-op: correct results, but not the compiled path
     segment_runtime_failures: int = 0
+    # the run's spans (core/spans.py): in a service, the whole
+    # super-batch's, shared by every job report of it
+    spans: list = field(default_factory=list)
+    # bytes that crossed between tiers: numpy inputs of device-tier ops
+    # (host→device) and device arrays handed to python-tier ops
+    # (device→host)
+    h2d_bytes: int = 0
+    d2h_bytes: int = 0
+
+
+#: tiers whose ops run on the device: a numpy input crosses to it
+DEVICE_TIERS = ("jax", "pallas")
+
+
+def crossing_bytes(tier: str, values) -> tuple:
+    """``(host→device, device→host)`` bytes of ``values`` handed to an op
+    of ``tier``: a numpy array given to a device-tier op crosses to the
+    device, a device array given to a python-tier op crosses to the
+    host."""
+    if tier in DEVICE_TIERS:
+        return sum(int(v.nbytes) for v in values
+                   if isinstance(v, np.ndarray)), 0
+    if tier == "python":
+        return 0, sum(int(v.nbytes) for v in values
+                      if isinstance(v, jax.Array))
+    return 0, 0
 
 
 class ExecutionError(RuntimeError):
@@ -200,6 +231,13 @@ class Runtime:
                 for key in self._keys_by_sig.pop(sig, ()):
                     self._values.pop(key, None)
 
+    def _note_crossing(self, tier: str, values, report: RunReport) -> None:
+        h2d, d2h = crossing_bytes(tier, values)
+        if h2d or d2h:
+            with self._lock:
+                report.h2d_bytes += h2d
+                report.d2h_bytes += d2h
+
     def _try_cache_hit(self, op: LazyOp, report: RunReport
                        ) -> Optional[tuple]:
         """ONE tenant-aware intermediate-cache probe; on a hit the value
@@ -209,7 +247,10 @@ class Runtime:
         if self.cache is None or not op.cacheable:
             return None
         sig = op.signature
-        hit = self.cache.get(sig, tenant=self.sig_tenant.get(sig))
+        with spans.span("stratum.cache.get", hit=False, bytes=0) as sp:
+            hit = self.cache.get(sig, tenant=self.sig_tenant.get(sig))
+            if hit is not None:
+                sp.attrs.update(hit=True, bytes=_nbytes(hit))
         if hit is None:
             return None
         self._store(op, hit)
@@ -217,6 +258,16 @@ class Runtime:
             report.ops_from_cache += 1
             report.sig_source[sig] = "cache"
         return hit
+
+    def _cache_put(self, op: LazyOp, outputs: tuple) -> None:
+        """Insert a marked candidate's outputs into the intermediate
+        cache, charged to the op's tenant."""
+        sig = op.signature
+        if (self.cache is None or not op.cacheable
+                or sig not in self.cache_candidates):
+            return
+        with spans.span("stratum.cache.put", bytes=_nbytes(outputs)):
+            self.cache.put(sig, outputs, tenant=self.sig_tenant.get(sig))
 
     def _run_ops_parallel(self, todo: list, selection: dict,
                           report: RunReport) -> None:
@@ -227,8 +278,11 @@ class Runtime:
         finished goes into the salvage."""
         pool = self._pool
         if pool is not None and len(todo) > 1:
-            run_op = on_device(self.device, self._run_op)
-            pending = {pool.submit(run_op, op, selection, report)
+            # pool threads do not inherit this thread's context: each op
+            # takes the open span as its parent explicitly
+            run_op = on_device(self.device, self._run_op_under)
+            parent = spans.current()
+            pending = {pool.submit(run_op, parent, op, selection, report)
                        for op in todo}
             while pending:
                 done, pending = _fwait(pending,
@@ -246,6 +300,11 @@ class Runtime:
                     raise self._preempted(report)
                 self._run_op(op, selection, report)
 
+    def _run_op_under(self, parent, op: LazyOp, selection: dict,
+                      report: RunReport) -> None:
+        with spans.attach(parent):
+            self._run_op(op, selection, report)
+
     def _run_op(self, op: LazyOp, selection: dict, report: RunReport) -> None:
         sig = op.signature
         if sig in self.preloaded:
@@ -256,9 +315,18 @@ class Runtime:
         if self._try_cache_hit(op, report) is not None:
             return
         inputs = self._gather_inputs(op)
+        impl = selection.get(sig)
+        backend = impl.backend if impl else "ref"
         fn = self._resolve_impl(op, selection)
+        self._note_crossing(backend, inputs, report)
+        # an impl that opens leaf spans of its own leaves its op span
+        # out of the profiler capture
+        mirror = (None if getattr(fn, "opens_spans", False)
+                  else "stratum.op." + op.op_name)
         try:
-            outputs = fn(op, inputs)
+            with spans.Span("stratum.op", {"op": op.op_name,
+                                           "tier": backend}, mirror):
+                outputs = fn(op, inputs)
         except Exception as e:  # noqa: BLE001 — surfaced with op context
             raise ExecutionError(op, e) from e
         if not isinstance(outputs, tuple):
@@ -268,15 +336,11 @@ class Runtime:
                 op, ValueError(f"impl returned {len(outputs)} outputs, "
                                f"declared {op.n_outputs}"))
         self._store(op, outputs)
-        impl = selection.get(sig)
-        backend = impl.backend if impl else "ref"
         with self._lock:
             report.ops_executed += 1
             report.per_backend[backend] = report.per_backend.get(backend, 0) + 1
             report.sig_source[sig] = backend
-        if (self.cache is not None and op.cacheable
-                and sig in self.cache_candidates):
-            self.cache.put(sig, outputs, tenant=self.sig_tenant.get(sig))
+        self._cache_put(op, outputs)
 
     # -- variant batching (§Perf H3.4) ---------------------------------
     def _batch_variants(self, wave_ops: list, selection: dict,
@@ -313,13 +377,13 @@ class Runtime:
                 continue
             _, batch_fn = vmap_group_for(op_name)
             inputs = self._gather_inputs(todo[0])
-            outs = batch_fn(todo, inputs)
+            self._note_crossing("jax", inputs, report)
+            with spans.Span("stratum.op", {"op": op_name, "tier": "jax-vmap"},
+                            "stratum.op." + op_name):
+                outs = batch_fn(todo, inputs)
             for op, out in zip(todo, outs):
                 self._store(op, out)
-                if (self.cache is not None and op.cacheable
-                        and op.signature in self.cache_candidates):
-                    self.cache.put(op.signature, out,
-                                   tenant=self.sig_tenant.get(op.signature))
+                self._cache_put(op, out)
             with self._lock:
                 report.ops_executed += len(todo)
                 report.per_backend["jax-vmap"] = \
@@ -386,15 +450,27 @@ class Runtime:
         segments = plan.segments or [Segment(kind="python",
                                              waves=list(plan.waves))]
         python_backend = self.backends["python"]
+        # spans land in the caller's open collection (a service's
+        # super-batch) or else in this run's own
+        cur = spans.current()
+        if cur is not None and cur.sink is not None:
+            report.spans = cur.sink
+            collection = contextlib.nullcontext()
+        else:
+            collection = spans.collect(report.spans)
         try:
-            for seg in segments:
-                # cooperative yield point at the segment boundary — the
-                # salvage carries every completed intermediate to the
-                # requeued re-run (python segments add wave/op-level polls)
-                if self._should_yield(report):
-                    raise self._preempted(report)
-                backend = self.backends.get(seg.kind, python_backend)
-                backend.execute_segment(self, seg, selection, report)
+            with collection, spans.scope("stratum.execute",
+                                         segments=len(segments)):
+                for seg in segments:
+                    # cooperative yield point at the segment boundary —
+                    # the salvage carries every completed intermediate to
+                    # the requeued re-run (python segments add wave/op-level
+                    # polls)
+                    if self._should_yield(report):
+                        raise self._preempted(report)
+                    backend = self.backends.get(seg.kind, python_backend)
+                    with spans.scope("stratum.segment", kind=seg.kind):
+                        backend.execute_segment(self, seg, selection, report)
         finally:
             if self._pool is not None:
                 # cancel queued work and wait for in-flight ops so an error

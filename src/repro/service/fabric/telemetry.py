@@ -21,7 +21,7 @@ from __future__ import annotations
 
 from ..control import merge_control_snapshots
 from ..observability import merge_window_snapshots
-from ..telemetry import merge_tenant_snapshots
+from ..telemetry import merge_compile_snapshots, merge_tenant_snapshots
 
 
 class FabricTelemetry:
@@ -59,6 +59,8 @@ class FabricTelemetry:
             "ops_deduped_cross_agent": g["ops_deduped_cross_agent"],
             "preemptions": g["preemptions"],
         }
+        if "compile" in g:
+            row["compile"] = g["compile"]
         if "plan_cache" in g:
             row["plan_cache"] = g["plan_cache"]
         if "windows" in g:
@@ -96,6 +98,8 @@ class FabricTelemetry:
             if "cache_cross_tenant_hits" in g:
                 out[shard_id]["cache_cross_tenant_hits"] = \
                     g["cache_cross_tenant_hits"]
+            if "compile" in g:
+                out[shard_id]["compile"] = g["compile"]
             if "plan_cache" in g:
                 out[shard_id]["plan_cache"] = g["plan_cache"]
             if "windows" in g:
@@ -145,6 +149,10 @@ class FabricTelemetry:
             "shed": d_shed,
             "attainment": (d_met / d_jobs) if d_jobs else 1.0,
         }
+        compile_rows = [s["compile"] for s in per_shard.values()
+                        if "compile" in s]
+        if compile_rows:
+            totals["compile"] = merge_compile_snapshots(compile_rows)
         pc_rows = [s["plan_cache"] for s in per_shard.values()
                    if "plan_cache" in s]
         if pc_rows:

@@ -1,6 +1,7 @@
 """Structured JSONL event log + the per-process trace sink.
 
-``TraceLog`` appends one JSON object per hop to
+``TraceLog`` appends one JSON object per hop (and per span, see
+:func:`span_record`) to
 ``<trace_dir>/events-<component>-<pid>.jsonl`` and flushes per line, so a
 SIGKILLed worker's already-stamped hops (e.g. the ``dispatched`` hop of
 the job it died holding) survive on disk and are recoverable by
@@ -36,6 +37,22 @@ def record_hop(rec: dict) -> tuple:
     """Inverse of :func:`hop_record` — rebuild the hop tuple."""
     return (rec["event"], rec["t"], rec.get("shard", ""),
             rec.get("slack"), dict(rec.get("detail", ())))
+
+
+def span_record(jobs, span) -> dict:
+    """JSON-safe record for one span of a super-batch whose jobs' trace
+    keys are ``jobs`` (a span line: it has ``span`` where a hop line has
+    ``event``)."""
+    sid, parent, name, t0_ns, t1_ns, attrs = span
+    return {"jobs": list(jobs), "span": sid, "parent": parent,
+            "name": name, "t0_ns": t0_ns, "t1_ns": t1_ns,
+            "attrs": dict(attrs)}
+
+
+def record_span(rec: dict) -> tuple:
+    """Inverse of :func:`span_record` — rebuild the span tuple."""
+    return (rec["span"], rec.get("parent"), rec["name"], rec["t0_ns"],
+            rec["t1_ns"], dict(rec.get("attrs", ())))
 
 
 class TraceLog:
@@ -126,6 +143,12 @@ class TraceSink:
     def emit_hop(self, key: str, tenant: str, hop) -> None:
         if self.log is not None:
             self.log.append(hop_record(key, tenant, hop))
+
+    def emit_spans(self, jobs, spans) -> None:
+        """Log a super-batch's spans under its jobs' trace keys."""
+        if self.log is not None:
+            for s in spans:
+                self.log.append(span_record(jobs, s))
 
     def close(self) -> None:
         if self.log is not None:

@@ -6,8 +6,11 @@ under the shared ``trace_dir``.  Replay merges them all, reassembles one
 per-job timeline (hops sorted by stamp time, de-duplicated on the full
 hop tuple — the same hop logged by two components counts once), and
 derives per-shard gantt summaries of dispatch→completion occupancy.
+Span lines (each super-batch's spans, logged under its jobs' keys) give
+the self time per span name, for one job or for the whole log.
 
     python -m repro.service.observability.replay /tmp/traces [--job KEY]
+    python -m repro.service.observability.replay /tmp/traces --spans
 """
 
 from __future__ import annotations
@@ -18,11 +21,14 @@ import json
 import os
 from collections import defaultdict
 
+from ...core.spans import self_seconds_by_name
+from .events import record_span
 from .trace import DISPATCHED, FAILOVER, PREEMPTED, TERMINAL
 
 
-def load_events(trace_dir: str) -> list:
-    """All hop records from every JSONL file under ``trace_dir``.
+def load_records(trace_dir: str) -> list:
+    """Every record, hop and span lines alike, from every JSONL file under
+    ``trace_dir``.
 
     A torn final line (process killed mid-write) is skipped, never fatal.
     """
@@ -43,6 +49,11 @@ def load_events(trace_dir: str) -> list:
     return records
 
 
+def load_events(trace_dir: str) -> list:
+    """All hop records under ``trace_dir`` (span lines left out)."""
+    return [r for r in load_records(trace_dir) if "span" not in r]
+
+
 def reassemble(records) -> dict:
     """Per-job timelines: ``{job_key: [hop_record, ...]}`` sorted by time.
 
@@ -60,6 +71,35 @@ def reassemble(records) -> dict:
     for hops in jobs.values():
         hops.sort(key=lambda r: r["t"])
     return dict(jobs)
+
+
+def spans_of(records, job=None) -> dict:
+    """``{log file: [span tuple, ...]}`` of the span lines among
+    ``records`` (:func:`load_records`), those of the
+    super-batches that ran ``job`` when given.  Span ids are unique per
+    process, so each file's spans form their own trees."""
+    out = defaultdict(list)
+    for rec in records:
+        if "span" in rec and (job is None or job in rec["jobs"]):
+            out[rec["source"]].append(record_span(rec))
+    return dict(out)
+
+
+def span_self_times(records, job=None) -> dict:
+    """``{span name: (self seconds, count)}`` over :func:`spans_of`."""
+    out: dict = {}
+    for spans in spans_of(records, job).values():
+        for name, (sec, n) in self_seconds_by_name(spans).items():
+            s0, n0 = out.get(name, (0.0, 0))
+            out[name] = (s0 + sec, n0 + n)
+    return out
+
+
+def format_span_times(times: dict) -> str:
+    lines = [f"{'span':<24} {'self s':>10} {'count':>7}"]
+    for name, (sec, n) in sorted(times.items(), key=lambda kv: -kv[1][0]):
+        lines.append(f"{name:<24} {sec:10.6f} {n:7d}")
+    return "\n".join(lines) if times else "(no spans)"
 
 
 def job_timeline(timelines: dict, key: str) -> list:
@@ -146,9 +186,16 @@ def main(argv=None) -> int:
     ap.add_argument("--job", help="print the full timeline of one job key")
     ap.add_argument("--gantt", action="store_true",
                     help="print per-shard dispatch spans")
+    ap.add_argument("--spans", action="store_true",
+                    help="print self time per span name (of --job's "
+                         "super-batches, or of the whole log)")
     args = ap.parse_args(argv)
 
-    timelines = reassemble(load_events(args.trace_dir))
+    records = load_records(args.trace_dir)
+    if args.spans:
+        print(format_span_times(span_self_times(records, args.job)))
+        return 0
+    timelines = reassemble([r for r in records if "span" not in r])
     summary = summarize(timelines)
     print(f"{summary['jobs']} jobs, outcomes {summary['outcomes']}, "
           f"{summary['failovers']} failovers")

@@ -83,6 +83,12 @@ def render(snapshot: dict) -> str:
             f"spec hits {cc.get('speculative_hits', 0)}  "
             f"compile {cc.get('compile_time_s', 0.0):.2f}s")
 
+    jit = snapshot.get("compile")
+    if jit:
+        lines.append(f"jit: {jit.get('n', 0)} programs compiled, "
+                     f"{jit.get('s', 0.0):.2f}s tracing, lowering and "
+                     f"compiling")
+
     shards = snapshot.get("per_shard") or {}
     if shards:
         lines.append(f"{'shard':<10} {'state':<8} {'depth':>5} "
@@ -141,6 +147,7 @@ def demo_snapshot() -> dict:
         "plan_cache_async_compiles": 7, "plan_cache_inflight": 1,
         "plan_cache_speculative_hits": 3,
         "plan_cache_compile_time_s": 1.37,
+        "compile": {"n": 21, "s": 4.82},
         "per_shard": {
             "shard0": {"state": "live", "queue_depth": 3, "inflight": 1,
                        "plan_cache": {"hits": 37, "misses": 5},

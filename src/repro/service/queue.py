@@ -92,6 +92,9 @@ class Job:
     salvage: dict = field(default_factory=dict)
     # live JobTrace when lifecycle tracing is on (observability/), else None
     trace: object = None
+    # spans recorded before dispatch (admission); moved into the first
+    # super-batch's spans (core/spans.py)
+    spans: list = field(default_factory=list)
 
     def __post_init__(self) -> None:
         if self.band < 0:

@@ -49,6 +49,7 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
+from ..core import spans
 from ..core.analysis import (AnalysisError, AnalysisReport, analyze,
                              validate_wiring)
 from ..core.api import ALL_FEATURES, Stratum
@@ -362,38 +363,43 @@ class StratumService:
         priority = Priority(priority)
         job_id = next(self._job_ids)
         future = PipelineFuture(job_id, tenant, priority)
-        trace = self.traces.begin(trace_key or f"j{job_id}", tenant,
-                                  hops=trace_hops)
+        # the admission span travels with the job into its super-batch's
+        # spans; it closes before the push, so a dispatcher that pops the
+        # job at once finds it recorded
+        admit: list = []
+        with spans.collect(admit), spans.span("stratum.admit", job=job_id):
+            trace = self.traces.begin(trace_key or f"j{job_id}", tenant,
+                                      hops=trace_hops)
 
-        def _cancel(jid: int) -> bool:
-            ok = self.queue.cancel(jid)
-            if ok:
-                self.telemetry.record_job_cancelled(tenant)
-                if trace is not None:
-                    trace.stamp(CANCELLED, shard=self.shard_id)
-                    self.traces.finish(trace)
-            return ok
+            def _cancel(jid: int) -> bool:
+                ok = self.queue.cancel(jid)
+                if ok:
+                    self.telemetry.record_job_cancelled(tenant)
+                    if trace is not None:
+                        trace.stamp(CANCELLED, shard=self.shard_id)
+                        self.traces.finish(trace)
+                return ok
 
-        future._cancel_hook = _cancel
-        job = Job(id=job_id, tenant=tenant, batch=batch, future=future,
-                  priority=priority, deadline_s=deadline_s,
-                  tags=tuple(tags), trace=trace)
-        if trace is not None and not trace_hops:
-            # a seeded trace (fabric continuation) was already stamped
-            # SUBMITTED client-side
-            trace.stamp(SUBMITTED, shard=self.shard_id,
-                        slack=self._slack(job), priority=priority.name)
-        do_verify = (verify if verify is not None
-                     else self.config.admission_analysis)
-        if do_verify:
-            try:
-                self._admission_analysis(tenant, batch, trace)
-            except AnalysisError:
-                if trace is not None:
-                    trace.stamp(FAILED, shard=self.shard_id,
-                                reason="analysis")
-                    self.traces.finish(trace)
-                raise
+            future._cancel_hook = _cancel
+            job = Job(id=job_id, tenant=tenant, batch=batch, future=future,
+                      priority=priority, deadline_s=deadline_s,
+                      tags=tuple(tags), trace=trace, spans=admit)
+            if trace is not None and not trace_hops:
+                # a seeded trace (fabric continuation) was already stamped
+                # SUBMITTED client-side
+                trace.stamp(SUBMITTED, shard=self.shard_id,
+                            slack=self._slack(job), priority=priority.name)
+            do_verify = (verify if verify is not None
+                         else self.config.admission_analysis)
+            if do_verify:
+                try:
+                    self._admission_analysis(tenant, batch, trace)
+                except AnalysisError:
+                    if trace is not None:
+                        trace.stamp(FAILED, shard=self.shard_id,
+                                    reason="analysis")
+                        self.traces.finish(trace)
+                    raise
         try:
             self.queue.push(job)           # may raise AdmissionError
         except AdmissionError:
@@ -678,11 +684,30 @@ class StratumService:
 
     def _execute_jobs(self, jobs: list, allow_retry: bool,
                       is_retry: bool = False) -> None:
-        now = time.perf_counter()
+        now, now_ns = time.perf_counter(), time.time_ns()
         live = [j for j in jobs if j.future._mark_running()]
         if not live:
             return
+        # one span tree per super-batch, shared by its job reports; the
+        # futures resolve once it is complete
+        sink: list = []
+        with spans.collect(sink), spans.scope(
+                "stratum.dispatch", jobs=[j.id for j in live],
+                n_jobs=len(live), retry=is_retry):
+            done = self._run_super_batch(live, now, now_ns, allow_retry,
+                                         is_retry)
+        for job, results, report in done:
+            job.future._set_result(results, report)
+        self.traces.emit_spans(
+            [j.trace.key if j.trace is not None else f"j{j.id}"
+             for j in live], sink)
+
+    def _run_super_batch(self, live: list, now: float, now_ns: int,
+                         allow_retry: bool, is_retry: bool) -> list:
+        """Coalesce, optimize and run ``live`` as one super-batch; the
+        ``(job, results, report)`` of each job that completed."""
         depth = self.queue.pending()
+        dispatch = spans.current()
         for job in live:
             # measure queue wait once, at first dispatch — a failure-isolation
             # retry must not re-record it (the second measurement would
@@ -692,6 +717,14 @@ class StratumService:
                 self.telemetry.record_dispatch(job.tenant,
                                                job.dispatch_wait_s,
                                                job.priority, depth=depth)
+                spans.record("stratum.queue",
+                             now_ns - int(job.dispatch_wait_s * 1e9), now_ns,
+                             job=job.id, band=job.band)
+                # the admission span joins this super-batch's tree
+                dispatch.sink.extend(
+                    (s[0], dispatch.id) + s[2:] if s[1] is None else s
+                    for s in job.spans)
+                job.spans = []
             if job.trace is not None:
                 slack = self._slack(job, now)
                 if len(live) > 1:
@@ -702,27 +735,32 @@ class StratumService:
                                 wait_s=round(job.dispatch_wait_s or 0.0, 6),
                                 retry=is_retry, resume=job.preemptions > 0)
 
-        merged: SuperBatch = coalesce(live)
+        with spans.span("stratum.coalesce", n_jobs=len(live)):
+            merged: SuperBatch = coalesce(live)
         try:
-            (sinks, sel, plan, candidates, rw, ops_submitted,
-             opt_time) = self._optimizer.compile_batch(merged.batch)
+            with spans.span("stratum.compile_batch") as sp:
+                (sinks, sel, plan, candidates, rw, ops_submitted,
+                 _opt_s) = self._optimizer.compile_batch(merged.batch)
+                sp.attrs["ops_submitted"] = ops_submitted
         except AnalysisError as e:
             # statically invalid pipeline in the merged batch: fail only
             # the offending jobs, re-run innocent coalesced bystanders
             # (mirrors the ExecutionError isolation below)
             self._isolate_invalid(live, e, allow_retry)
-            return
+            return []
         except Exception as e:  # noqa: BLE001 — propagate via futures
             self._fail_jobs(live, e)
-            return
+            return []
 
         # post-optimization per-job reachable sets: used for cross-agent
         # dedup accounting, failure isolation, cache charge attribution and
         # telemetry attribution
-        job_sigs = [reachable_sigs(merged.job_sinks(sinks, j))
-                    for j in range(len(live))]
-        deduped, shared = cross_agent_dedup(job_sigs,
-                                            [j.tenant for j in live])
+        with spans.span("stratum.coalesce") as sp:
+            job_sigs = [reachable_sigs(merged.job_sinks(sinks, j))
+                        for j in range(len(live))]
+            deduped, shared = cross_agent_dedup(job_sigs,
+                                                [j.tenant for j in live])
+            sp.attrs.update(n_ops=sum(map(len, job_sigs)), deduped=deduped)
         if not is_retry and not any(j.preemptions for j in live):
             # neither a failure-isolation retry nor a post-preemption
             # re-dispatch is a new super-batch for accounting purposes
@@ -756,7 +794,7 @@ class StratumService:
         except ExecutionPreempted as p:
             self._release_mem(need)
             self._requeue_preempted(live, job_sigs, p)
-            return
+            return []
         except ExecutionError as e:
             self._release_mem(need)
             bad_sig = e.op.signature
@@ -764,7 +802,7 @@ class StratumService:
             good = [j for j in live if j not in bad]
             if not bad:          # can't attribute → fail the whole batch
                 self._fail_jobs(live, e)
-                return
+                return []
             self._fail_jobs(bad, e)
             if good:
                 if allow_retry:
@@ -773,13 +811,20 @@ class StratumService:
                                        is_retry=True)
                 else:
                     self._fail_jobs(good, e)
-            return
+            return []
         except Exception as e:  # noqa: BLE001
             self._release_mem(need)
             self._fail_jobs(live, e)
-            return
+            return []
         self._release_mem(need)
 
+        with spans.span("stratum.commit", n_jobs=len(live)):
+            return self._commit(live, merged, results, run, rw, job_sigs,
+                                shared)
+
+    def _commit(self, live, merged, results, run, rw, job_sigs,
+                shared) -> list:
+        done = []
         named = dict(zip(merged.batch.names, results))
         per_job = merged.split_results(named)
         for j, (job, job_results) in enumerate(zip(live, per_job)):
@@ -826,4 +871,5 @@ class StratumService:
             self.telemetry.record_job_done(job.tenant, job_sigs[j],
                                            run.sig_source)
             job.salvage = {}    # release pinned intermediates
-            job.future._set_result(job_results, report)
+            done.append((job, job_results, report))
+        return done

@@ -19,9 +19,11 @@ bytes charged per tenant, per-tenant evictions, and cross-tenant hits
 
 from __future__ import annotations
 
+import os
 import threading
 from dataclasses import dataclass, field
 
+from ..core.spans import compile_totals
 from .observability import merge_window_snapshots
 from .priority import Priority
 
@@ -102,6 +104,20 @@ def merge_tenant_snapshots(snapshots) -> dict:
                 else:
                     out[k] = out.get(k, 0) + v
     return merged
+
+
+def merge_compile_snapshots(rows) -> dict:
+    """Fabric-wide compiles from shards' ``"compile"`` blocks.  The
+    counters are per process, so shards of one process report the same
+    ones: each process counts once (its latest, largest reading) and the
+    processes sum."""
+    latest: dict = {}
+    for r in rows:
+        cur = latest.get(r.get("pid"))
+        if cur is None or (r["n"], r["s"]) > (cur["n"], cur["s"]):
+            latest[r.get("pid")] = r
+    return {"n": sum(r["n"] for r in latest.values()),
+            "s": sum(r["s"] for r in latest.values())}
 
 
 class ServiceTelemetry:
@@ -258,6 +274,9 @@ class ServiceTelemetry:
                 "jobs_coalesced": self.jobs_coalesced,
                 "ops_deduped_cross_agent": self.ops_deduped_cross_agent,
                 "preemptions": self.preemptions,
+                # JAX compiles of this process (core/spans.py), the GBT's
+                # direct jits and background plan compiles included
+                "compile": dict(compile_totals(), pid=os.getpid()),
                 # deadline attainment across every tenant of this shard
                 "deadline": {
                     "jobs": d_jobs,

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.spans import span
+
 N_BINS = 32  # fixed power-of-two bin count
 
 
@@ -274,9 +276,11 @@ def fit_jax(X: np.ndarray, y: np.ndarray, *, n_trees: int = 30,
             depth: int = 3, lr: float = 0.1, reg: float = 1.0,
             subsample: float = 1.0, seed: int = 0,
             dense: bool = False) -> np.ndarray:
-    # binning on host (cheap, one pass), training compiled
-    bins = make_bins(X)
-    B = bin_data(X, bins)
+    # binning on host (one pass), training compiled
+    n, F = X.shape
+    with span("stratum.gbt.bin", n=n, F=F):
+        bins = make_bins(X)
+        B = bin_data(X, bins)
     base = float(np.mean(y))
     if subsample < 1.0:
         # deterministic row subsample per seed (applied once — cheaper than
@@ -286,12 +290,17 @@ def fit_jax(X: np.ndarray, y: np.ndarray, *, n_trees: int = 30,
         B_fit, y_fit = B[keep], y[keep]
     else:
         B_fit, y_fit = B, y
-    feats, thrs, leaves = _fit_jax_binned(
-        jnp.asarray(B_fit), jnp.asarray(y_fit, dtype=jnp.float32),
-        base, lr, reg, n_trees, depth, N_BINS, dense)
-    return pack(base, bins, np.asarray(feats).reshape(n_trees, -1),
-                np.asarray(thrs).reshape(n_trees, -1),
-                np.asarray(leaves, dtype=np.float64).reshape(n_trees, -1),
+    with span("stratum.gbt.put", bytes=int(B_fit.nbytes) + 4 * y_fit.size):
+        B_dev = jnp.asarray(B_fit)
+        y_dev = jnp.asarray(y_fit, dtype=jnp.float32)
+    with span("stratum.gbt.fit", n=int(B_fit.shape[0]), F=F,
+              n_trees=n_trees, depth=depth):
+        feats, thrs, leaves = _fit_jax_binned(
+            B_dev, y_dev, base, lr, reg, n_trees, depth, N_BINS, dense)
+        feats, thrs = np.asarray(feats), np.asarray(thrs)
+        leaves = np.asarray(leaves, dtype=np.float64)
+    return pack(base, bins, feats.reshape(n_trees, -1),
+                thrs.reshape(n_trees, -1), leaves.reshape(n_trees, -1),
                 depth)
 
 
@@ -317,7 +326,11 @@ def _predict_jax(B, feats, thrs, leaves, base, depth: int,
 def predict_jax(model: np.ndarray, X: np.ndarray, *,
                 dense: bool = False) -> np.ndarray:
     base, bins, feats, thrs, leaves, depth = unpack(model, X.shape[1])
-    B = bin_data(X, bins)
-    out = _predict_jax(jnp.asarray(B), jnp.asarray(feats), jnp.asarray(thrs),
-                       jnp.asarray(leaves), base, depth, dense)
-    return np.asarray(out)
+    with span("stratum.gbt.bin", n=X.shape[0], F=X.shape[1]):
+        B = bin_data(X, bins)
+    host = (B, feats, thrs, leaves)
+    with span("stratum.gbt.put", bytes=sum(int(a.nbytes) for a in host)):
+        dev = [jnp.asarray(a) for a in host]
+    with span("stratum.gbt.predict", n=X.shape[0], n_trees=feats.shape[0],
+              depth=depth):
+        return np.asarray(_predict_jax(*dev, base, depth, dense))

@@ -25,6 +25,7 @@ from ..core.dag import LazyOp, declare_tunable
 from ..core.metadata import OpMetadata, TensorInfo, register_meta
 from ..core.rewrites import declare_columnwise
 from ..core.selection import register_impl
+from ..core.spans import opens_spans, span
 from ..data import tabular as datasets
 from . import gbt
 
@@ -711,9 +712,18 @@ def gbt_py(op, ins):
 # Two forms of the jax GBT, one per platform: the TPU serialises scatter-adds
 # with colliding ids and per-row gathers, so it grows the trees by matmul
 # histograms and one-hot lookups; the CPU fits 4.8x faster in the scatter form.
+def _gbt_host(*values) -> tuple:
+    """The GBT's inputs as float64 host arrays: a device array is copied
+    to the host (``bytes``: the device arrays' size)."""
+    with span("stratum.gbt.get",
+              bytes=sum(int(v.nbytes) for v in values
+                        if isinstance(v, jax.Array))):
+        return tuple(np.asarray(v, dtype=np.float64) for v in values)
+
+
 def _gbt_fit_jax(op, ins, dense: bool):
-    X, y = np.asarray(ins[0], dtype=np.float64), \
-        np.asarray(ins[1], dtype=np.float64).ravel()
+    X, y = _gbt_host(ins[0], ins[1])
+    y = y.ravel()
     s = op.spec
     return (gbt.fit_jax(X, y, n_trees=s["n_trees"], depth=s["depth"],
                         lr=s["learning_rate"], reg=s["reg"],
@@ -722,11 +732,13 @@ def _gbt_fit_jax(op, ins, dense: bool):
 
 
 @register_impl("gbt_fit", "jax", platforms=("cpu", "gpu"))
+@opens_spans
 def gbt_jx(op, ins):
     return _gbt_fit_jax(op, ins, dense=False)
 
 
 @register_impl("gbt_fit", "jax", platforms=("tpu",))
+@opens_spans
 def gbt_jx_dense(op, ins):
     return _gbt_fit_jax(op, ins, dense=True)
 
@@ -772,16 +784,15 @@ def gbtpred_py(op, ins):
 
 
 @register_impl("gbt_predict", "jax", platforms=("cpu", "gpu"))
+@opens_spans
 def gbtpred_jax(op, ins):
-    return (gbt.predict_jax(np.asarray(ins[0]),
-                            np.asarray(ins[1], dtype=np.float64)),)
+    return (gbt.predict_jax(*_gbt_host(ins[0], ins[1])),)
 
 
 @register_impl("gbt_predict", "jax", platforms=("tpu",))
+@opens_spans
 def gbtpred_jax_dense(op, ins):
-    return (gbt.predict_jax(np.asarray(ins[0]),
-                            np.asarray(ins[1], dtype=np.float64),
-                            dense=True),)
+    return (gbt.predict_jax(*_gbt_host(ins[0], ins[1]), dense=True),)
 
 
 @register_meta("gbt_predict")
