@@ -31,8 +31,10 @@ N_BINS = 32  # fixed power-of-two bin count
 # ---------------------------------------------------------------------------
 
 def make_bins(X: np.ndarray, n_bins: int = N_BINS) -> np.ndarray:
-    """(F, n_bins-1) ascending split thresholds per feature."""
+    """(F, n_bins-1) ascending split thresholds per feature: float64
+    quantiles, whatever the features' dtype."""
     qs = np.linspace(0, 1, n_bins + 1)[1:-1]
+    X = np.asarray(X, dtype=np.float64)
     return np.nanquantile(X, qs, axis=0).T.copy()  # (F, n_bins-1)
 
 
@@ -43,6 +45,76 @@ def bin_data(X: np.ndarray, bins: np.ndarray) -> np.ndarray:
         out[:, j] = np.searchsorted(bins[j], X[:, j], side="right")
     out[np.isnan(X)] = 0
     return np.clip(out, 0, bins.shape[1])
+
+
+_TINY32 = np.finfo(np.float32).tiny
+
+
+def _subnormal(a: np.ndarray) -> bool:
+    """Whether ``a`` holds a subnormal value, 4096 rows at a time so the
+    temporaries stay in cache (half the time of one pass)."""
+    return any(np.any((np.abs(b) < _TINY32) & (b != 0))
+               for b in (a[i:i + 4096] for i in range(0, len(a), 4096)))
+
+
+def _device_edges(X: np.ndarray, bins: np.ndarray):
+    """The float32 edges :func:`_digitize` compares ``X`` against, or None
+    where its bins could differ from ``bin_data(X, bins)``.
+
+    For a float32 ``x`` and a float64 edge ``t``, ``x >= t`` holds exactly
+    when ``x >= c``, ``c`` the least float32 ``>= t``; and counting the
+    edges ``x`` reaches is ``searchsorted(side="right")`` where each row of
+    edges ascends with any NaN last.  None for features that are not
+    float32, edges that do not so ascend (quantiles over ±inf give NaN
+    between numbers), and subnormal features or edges, which a TPU (and
+    XLA's CPU) flushes to zero."""
+    if X.dtype != np.float32 or _subnormal(X):
+        return None
+    if not np.all(np.isnan(bins[:, 1:]) | (bins[:, :-1] <= bins[:, 1:])):
+        return None
+    c = bins.astype(np.float32)
+    low = c < bins
+    c[low] = np.nextafter(c[low], np.float32(np.inf))
+    return None if _subnormal(c) else c
+
+
+@partial(jax.jit, donate_argnums=0)
+def _digitize(X, edges):
+    """Bin ids of float32 features ``(n, F)`` against float32 edges
+    ``(F, n_bins-1)``: the count of edges each value reaches, so NaN lands
+    in bin 0.  The comparisons accumulate edge by edge into one fused pass,
+    never an ``(n, F, n_bins-1)`` tensor, and the int32 bin ids take the
+    donated features' buffer: a fit waiting behind others on the device
+    holds one ``(n, F)`` array, as when the host binned."""
+    B = jnp.zeros(X.shape, jnp.int32)
+    for k in range(edges.shape[1]):
+        B = B + (X >= edges[:, k]).astype(jnp.int32)
+    return B
+
+
+def _put(*host) -> list:
+    with span("stratum.gbt.put", bytes=sum(int(a.nbytes) for a in host)):
+        return [jnp.asarray(a) for a in host]
+
+
+def _binned(X: np.ndarray, edges_of, n: int):
+    """``(bins, B)``: the float64 edges ``edges_of()`` gives (``n`` rows
+    went into them) and ``X``'s bin ids against them, on the device.
+
+    Float32 features are put as they are and digitized on the device; the
+    host digitizes anything else (:func:`_device_edges`) and puts the bin
+    ids.  The ``stratum.gbt.bin`` span holds the host's share — the edges,
+    and the digitize where the host does it — and the dispatch of
+    :func:`_digitize`, not its wait; its ``on`` says which digitized."""
+    X_dev = _put(X)[0] if X.dtype == np.float32 else None
+    with span("stratum.gbt.bin", n=n, F=X.shape[1], on="device") as sp:
+        bins = edges_of()
+        edges = _device_edges(X, bins)
+        if edges is not None:
+            return bins, _digitize(X_dev, edges)
+        sp.attrs["on"] = "host"
+        B = bin_data(X, bins)
+    return bins, _put(B)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -276,24 +348,21 @@ def fit_jax(X: np.ndarray, y: np.ndarray, *, n_trees: int = 30,
             depth: int = 3, lr: float = 0.1, reg: float = 1.0,
             subsample: float = 1.0, seed: int = 0,
             dense: bool = False) -> np.ndarray:
-    # binning on host (one pass), training compiled
+    # quantile edges on the host over every row, training compiled
     n, F = X.shape
-    with span("stratum.gbt.bin", n=n, F=F):
-        bins = make_bins(X)
-        B = bin_data(X, bins)
     base = float(np.mean(y))
     if subsample < 1.0:
         # deterministic row subsample per seed (applied once — cheaper than
-        # per-round; documented deviation of the fast tier)
+        # per-round; documented deviation of the fast tier); a row's bins
+        # do not depend on the other rows, so only the kept rows are binned
         rng = np.random.default_rng(seed)
-        keep = rng.random(X.shape[0]) < subsample
-        B_fit, y_fit = B[keep], y[keep]
+        keep = rng.random(n) < subsample
+        X_fit, y_fit = X[keep], y[keep]
     else:
-        B_fit, y_fit = B, y
-    with span("stratum.gbt.put", bytes=int(B_fit.nbytes) + 4 * y_fit.size):
-        B_dev = jnp.asarray(B_fit)
-        y_dev = jnp.asarray(y_fit, dtype=jnp.float32)
-    with span("stratum.gbt.fit", n=int(B_fit.shape[0]), F=F,
+        X_fit, y_fit = X, y
+    bins, B_dev = _binned(X_fit, lambda: make_bins(X), n)
+    (y_dev,) = _put(np.asarray(y_fit, dtype=np.float32))
+    with span("stratum.gbt.fit", n=int(B_dev.shape[0]), F=F,
               n_trees=n_trees, depth=depth):
         feats, thrs, leaves = _fit_jax_binned(
             B_dev, y_dev, base, lr, reg, n_trees, depth, N_BINS, dense)
@@ -326,11 +395,8 @@ def _predict_jax(B, feats, thrs, leaves, base, depth: int,
 def predict_jax(model: np.ndarray, X: np.ndarray, *,
                 dense: bool = False) -> np.ndarray:
     base, bins, feats, thrs, leaves, depth = unpack(model, X.shape[1])
-    with span("stratum.gbt.bin", n=X.shape[0], F=X.shape[1]):
-        B = bin_data(X, bins)
-    host = (B, feats, thrs, leaves)
-    with span("stratum.gbt.put", bytes=sum(int(a.nbytes) for a in host)):
-        dev = [jnp.asarray(a) for a in host]
+    _, B_dev = _binned(X, lambda: bins, X.shape[0])
+    dev = [B_dev, *_put(feats, thrs, leaves)]
     with span("stratum.gbt.predict", n=X.shape[0], n_trees=feats.shape[0],
               depth=depth):
         return np.asarray(_predict_jax(*dev, base, depth, dense))

@@ -712,13 +712,20 @@ def gbt_py(op, ins):
 # Two forms of the jax GBT, one per platform: the TPU serialises scatter-adds
 # with colliding ids and per-row gathers, so it grows the trees by matmul
 # histograms and one-hot lookups; the CPU fits 4.8x faster in the scatter form.
-def _gbt_host(*values) -> tuple:
-    """The GBT's inputs as float64 host arrays: a device array is copied
-    to the host (``bytes``: the device arrays' size)."""
+def _gbt_host(X, *rest) -> tuple:
+    """The GBT's features and other inputs as host arrays: a device array
+    is copied to the host (``bytes``: the device arrays' size).  Float32
+    features stay float32, which the device digitizes exactly (the fit
+    widens them for its edges); other features and every other input are
+    float64."""
+    values = (X, *rest)
     with span("stratum.gbt.get",
               bytes=sum(int(v.nbytes) for v in values
                         if isinstance(v, jax.Array))):
-        return tuple(np.asarray(v, dtype=np.float64) for v in values)
+        X = np.asarray(X)
+        if X.dtype != np.float32:
+            X = X.astype(np.float64, copy=False)
+        return (X, *(np.asarray(v, dtype=np.float64) for v in rest))
 
 
 def _gbt_fit_jax(op, ins, dense: bool):
@@ -786,13 +793,15 @@ def gbtpred_py(op, ins):
 @register_impl("gbt_predict", "jax", platforms=("cpu", "gpu"))
 @opens_spans
 def gbtpred_jax(op, ins):
-    return (gbt.predict_jax(*_gbt_host(ins[0], ins[1])),)
+    X, model = _gbt_host(ins[1], ins[0])
+    return (gbt.predict_jax(model, X),)
 
 
 @register_impl("gbt_predict", "jax", platforms=("tpu",))
 @opens_spans
 def gbtpred_jax_dense(op, ins):
-    return (gbt.predict_jax(*_gbt_host(ins[0], ins[1]), dense=True),)
+    X, model = _gbt_host(ins[1], ins[0])
+    return (gbt.predict_jax(model, X, dense=True),)
 
 
 @register_meta("gbt_predict")

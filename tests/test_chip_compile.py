@@ -117,3 +117,25 @@ def test_gbt_fit_compiles_for_v5e(iteration1_plan, one_chip,
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < total < HBM_BYTES
+
+
+def test_gbt_digitize_compiles_for_v5e_without_a_per_edge_tensor(
+        iteration1_plan, one_chip, no_persistent_cache):
+    """The device digitize of the widest GBT fit's features: one fused
+    pass that holds no ``(rows, features, edges)`` temporary, its bin ids
+    in the donated features' buffer."""
+    import jax
+    import jax.numpy as jnp
+    from repro.tabular import gbt
+    _backend, _sel, plan = iteration1_plan
+    rows, cols = max(
+        (op.inputs[0].op.meta.outputs[op.inputs[0].index].shape
+         for s in plan.segments for w in s.waves for op in w.ops
+         if op.op_name == "gbt_fit"), key=lambda shape: shape[1])
+    X = jax.ShapeDtypeStruct((rows, cols), jnp.float32, sharding=one_chip)
+    edges = jax.ShapeDtypeStruct((cols, gbt.N_BINS - 1), jnp.float32,
+                                 sharding=one_chip)
+    mem = gbt._digitize.lower(X, edges).compile().memory_analysis()
+    assert mem.output_size_in_bytes >= rows * cols * 4
+    assert mem.temp_size_in_bytes < rows * cols * 4
+    assert mem.alias_size_in_bytes == mem.output_size_in_bytes

@@ -200,6 +200,25 @@ def test_leaf_spans_appear_in_the_profiler_capture_on_their_clock(tmp_path):
         assert name not in starts
 
 
+@pytest.mark.parametrize("dtype,on", [(np.float32, "device"),
+                                      (np.float64, "host")])
+def test_gbt_bin_span_says_where_the_features_were_digitized(dtype, on):
+    rng = np.random.default_rng(1)
+    X = rng.normal(size=(400, 5)).astype(dtype)
+    y = rng.normal(size=400)
+    sink: list = []
+    with spans.collect(sink):
+        model = gbt.fit_jax(X, y, n_trees=3, depth=2)
+        gbt.predict_jax(model, X)
+    bins = [s[5] for s in sink if s[2] == "stratum.gbt.bin"]
+    assert [b["on"] for b in bins] == [on, on]
+    assert [(b["n"], b["F"]) for b in bins] == [(400, 5), (400, 5)]
+    puts = [s[5]["bytes"] for s in sink if s[2] == "stratum.gbt.put"]
+    # the fit puts the features (float32, or their int32 bins) and the
+    # float32 target; the predict the features and the model's trees
+    assert puts[:2] == [400 * 5 * 4, 400 * 4]
+
+
 def test_an_op_whose_impl_opens_spans_is_not_mirrored():
     from repro.core.selection import impls_for
     for op_name in ("gbt_fit", "gbt_predict"):

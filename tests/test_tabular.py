@@ -156,6 +156,131 @@ def test_gbt_dense_form_grows_the_same_trees(depth):
     np.testing.assert_allclose(preds[0], preds[1], rtol=1e-6)
 
 
+def _at_edges(col):
+    """A float32 column's values, then each of its edges as float32 and
+    the float32 values on either side of it (but subnormal ones)."""
+    t = gbt.make_bins(col[:, None])[0]
+    c = t.astype(np.float32)
+    near = np.concatenate([c, np.nextafter(c, np.float32(np.inf)),
+                           np.nextafter(c, np.float32(-np.inf))])
+    near = near[(near == 0) | (np.abs(near) >= np.finfo(np.float32).tiny)]
+    return np.concatenate([col, near]).astype(np.float32)
+
+
+def _digitize_case(name):
+    rng = np.random.default_rng(3)
+    n = 997
+    if name == "at_edges":                      # ties, hit edges exactly
+        return _at_edges(np.round(rng.normal(size=n) * 3).astype(np.float32))
+    if name == "unrepresentable_edges":         # lerps float32 cannot hold
+        col = (np.float32(1 / 3) * rng.integers(0, 9, n)
+               + np.float32(1e-3) * rng.normal(size=n)).astype(np.float32)
+        return _at_edges(col)
+    col = rng.normal(size=n).astype(np.float32)
+    if name == "nan":
+        col[::7] = np.nan
+    elif name == "inf":                         # few enough for finite edges
+        col[::97], col[1::89] = np.inf, -np.inf
+    elif name == "signed_zero":
+        col[::3], col[1::3] = 0.0, -0.0
+    elif name == "constant":
+        col[:] = 2.5
+    elif name == "all_nan":
+        col[:] = np.nan
+    return col
+
+
+@pytest.mark.filterwarnings("ignore::RuntimeWarning")
+@pytest.mark.parametrize("case", [
+    "at_edges", "unrepresentable_edges", "nan", "inf", "signed_zero",
+    "constant", "all_nan"])
+def test_device_digitize_matches_host_bins_bit_for_bit(case):
+    """``_digitize`` against the float32 ceilings of the float64 edges
+    gives ``bin_data``'s bins exactly, beside an ordinary column."""
+    import jax.numpy as jnp
+    col = _digitize_case(case)
+    other = np.random.default_rng(4).normal(size=col.size)
+    X = np.stack([col, other.astype(np.float32)], axis=1)
+    bins = gbt.make_bins(X)
+    edges = gbt._device_edges(X, bins)
+    assert edges is not None and edges.dtype == np.float32
+    assert np.all(edges[~np.isnan(bins)] >= bins[~np.isnan(bins)])
+    np.testing.assert_array_equal(
+        np.asarray(gbt._digitize(jnp.asarray(X), edges)),
+        gbt.bin_data(X, bins))
+
+
+def _host_case(name):
+    rng = np.random.default_rng(5)
+    X = rng.normal(size=(500, 3)).astype(np.float32)
+    tiny = np.finfo(np.float32).tiny
+    if name == "float64":
+        return X.astype(np.float64)
+    if name == "subnormal_feature":
+        X[::9, 1] = tiny / 4
+    elif name == "subnormal_edge":              # median between 0 and tiny
+        X[:, 1] = np.where(np.arange(500) % 2, tiny, 0).astype(np.float32)
+    elif name == "edges_not_ascending":         # quantiles over ±inf: NaN
+        X[::5, 1], X[1::5, 1] = np.inf, -np.inf
+    return X
+
+
+@pytest.mark.filterwarnings("ignore::RuntimeWarning")
+@pytest.mark.parametrize("case", [
+    "float64", "subnormal_feature", "subnormal_edge", "edges_not_ascending"])
+def test_features_the_device_cannot_digitize_exactly_bin_on_the_host(case):
+    X = _host_case(case)
+    bins = gbt.make_bins(X)
+    assert gbt._device_edges(X, bins) is None
+    sink: list = []
+    from repro.core import spans
+    with spans.collect(sink):
+        model = gbt.fit_jax(X, X[:, 0].astype(np.float64), n_trees=2,
+                            depth=2)
+        gbt.predict_jax(model, X)
+    assert [s[5]["on"] for s in sink if s[2] == "stratum.gbt.bin"] == \
+        ["host", "host"]
+
+
+@pytest.mark.parametrize("dense", [False, True])
+@pytest.mark.parametrize("subsample", [1.0, 0.9])
+def test_fit_and_predict_on_float32_features_match_host_binning(
+        dense, subsample):
+    """``fit_jax`` packs and ``predict_jax`` outputs on float32 features,
+    digitized on the device, equal those of the host-binned path bit for
+    bit."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(6)
+    X = rng.normal(size=(800, 7)).astype(np.float32)
+    X[:, 2] = np.round(X[:, 2] * 2)
+    X[::13, 3] = np.nan
+    y = X[:, 0] * 2.0 + np.sin(np.nan_to_num(X[:, 3])) \
+        + 0.1 * rng.normal(size=800)
+    T, depth, lr, reg, seed = 6, 3, 0.1, 1.0, 11
+    model = gbt.fit_jax(X, y, n_trees=T, depth=depth, lr=lr, reg=reg,
+                        subsample=subsample, seed=seed, dense=dense)
+    bins = gbt.make_bins(X)
+    assert gbt._device_edges(X, bins) is not None
+    B, y_fit = gbt.bin_data(X, bins), y
+    if subsample < 1.0:
+        keep = np.random.default_rng(seed).random(800) < subsample
+        B, y_fit = B[keep], y[keep]
+    base = float(np.mean(y))
+    feats, thrs, leaves = gbt._fit_jax_binned(
+        jnp.asarray(B), jnp.asarray(y_fit, jnp.float32), base, lr, reg, T,
+        depth, gbt.N_BINS, dense)
+    expected = gbt.pack(base, bins, np.asarray(feats), np.asarray(thrs),
+                        np.asarray(leaves, np.float64), depth)
+    np.testing.assert_array_equal(model, expected)
+    pred = gbt._predict_jax(
+        *(jnp.asarray(a) for a in (gbt.bin_data(X, bins), np.asarray(feats),
+                                   np.asarray(thrs),
+                                   np.asarray(leaves, np.float64))),
+        base, depth, dense)
+    np.testing.assert_array_equal(gbt.predict_jax(model, X, dense=dense),
+                                  np.asarray(pred))
+
+
 @pytest.mark.parametrize("platform,form", [
     ("tpu", "gbt_jx_dense"), ("cpu", "gbt_jx"), ("gpu", "gbt_jx")])
 def test_selection_picks_the_gbt_form_of_the_platform(platform, form):
