@@ -1,5 +1,6 @@
 """Spans the benchmark records around calls into the program's layers,
-for the traced run only: each GBT fit program with its shape.
+for the traced run only: each GBT fit program with its shape and the chip
+it was dispatched to.
 
 They wrap the program's callables from outside and put them back after
 the window; the program itself records no spans yet."""
@@ -13,7 +14,7 @@ import time
 class Probes:
     def __init__(self):
         self.lock = threading.Lock()
-        self.fits = []           # (start, n, F, n_trees, depth)
+        self.fits = []           # (start, n, F, n_trees, depth, chip)
         self._undo = []
 
     def wrap(self, owner, attr: str, record) -> None:
@@ -36,6 +37,13 @@ class Probes:
         self._undo.clear()
 
 
+def chip(array) -> int:
+    """The ordinal of the chip that holds ``array``, as the trace names
+    its plane (``/device:TPU:<ordinal>``)."""
+    (device,) = array.devices()
+    return device.local_hardware_id
+
+
 def install() -> Probes:
     from repro.tabular import gbt
 
@@ -45,6 +53,7 @@ def install() -> Probes:
         n, F = args[0].shape
         p.fits.append((t0, n, F, args[5] if len(args) > 5
                        else kwargs["n_trees"],
-                       args[6] if len(args) > 6 else kwargs["depth"]))
+                       args[6] if len(args) > 6 else kwargs["depth"],
+                       chip(args[0])))
     p.wrap(gbt, "_fit_jax_binned", fit)
     return p

@@ -6,10 +6,13 @@ reads the device's events from the written ``.xplane.pb`` and gives
 * ``busy_s``: the union of the intervals in which a program ran on the
   device (the ``XLA Modules`` line of each TPU plane), averaged over the
   chips, and ``window_s``, the traced window's length;
+* ``programs``: each chip's program events in the order they ran, keyed
+  by the chip's ordinal (``/device:TPU:<ordinal>``);
 * ``modules``: summed device seconds and event count per program name;
 * ``device_ops`` and ``idle_gaps`` for the ``breakdown``: the programs
-  that took most device time, and the longest gaps between device work,
-  each named by the host event that overlaps it most.
+  that took most device time summed over the chips, and the longest gaps
+  in which no chip ran a program, each named by the host event that
+  overlaps it most.
 
 The window is the host span ``bench.window`` that ``Profiler`` opens and
 closes around it; device and host events share the trace's clock to
@@ -44,12 +47,13 @@ class Summary:
     modules: dict = field(default_factory=dict)  # name -> [seconds, count]
     device_ops: list = field(default_factory=list)
     idle_gaps: list = field(default_factory=list)
-    programs: list = field(default_factory=list)  # first chip's, in order
+    programs: dict = field(default_factory=dict)  # chip -> events, in order
 
-    def program_events(self, part: str) -> list:
-        """The first chip's events of programs whose name holds ``part``,
-        in the order they ran."""
-        return [e for e in self.programs if part in e.name]
+    def program_events(self, part: str) -> dict:
+        """``{chip: events}``: each chip's events of programs whose name
+        holds ``part``, in the order they ran."""
+        return {chip: [e for e in events if part in e.name]
+                for chip, events in self.programs.items()}
 
 
 def union_seconds(intervals: list) -> float:
@@ -91,16 +95,18 @@ def _label(gap: tuple, host: list) -> str:
     return best
 
 
-def summarize(devices: list, host: list, window: tuple,
+def summarize(devices: dict, host: list, window: tuple,
               top: int = 10) -> Summary:
-    """``devices``: per chip, its program events; ``host``: host events;
-    ``window``: ``(lo, hi)`` of the traced window on the trace clock."""
+    """``devices``: each chip's program events, ``{ordinal: events}``;
+    ``host``: host events; ``window``: ``(lo, hi)`` of the traced window
+    on the trace clock."""
     lo, hi = window
     busy, modules = [], {}
     merged = []
-    devices = [sorted((e for e in events if lo <= e.start < hi),
-                      key=lambda e: e.start) for events in devices]
-    for events in devices:
+    devices = {chip: sorted((e for e in events if lo <= e.start < hi),
+                            key=lambda e: e.start)
+               for chip, events in sorted(devices.items())}
+    for events in devices.values():
         iv = [(e.start, min(e.start + e.dur, hi)) for e in events]
         busy.append(union_seconds(iv))
         merged.extend(iv)
@@ -110,7 +116,7 @@ def summarize(devices: list, host: list, window: tuple,
             row[1] += 1
     ops = sorted(((name, sec) for name, (sec, _) in modules.items()),
                  key=lambda x: -x[1])[:top]
-    # gaps of the first chip: with one chip per cell this is the chip
+    # the stretches in which no chip ran a program
     idle = gaps([(s, e) for s, e in merged], lo, hi) if devices else []
     longest = sorted(idle, key=lambda g: g[0] - g[1])[:top]
     hosts = [h for h in host if h.dur > 0 and h.name != WINDOW_SPAN]
@@ -119,21 +125,25 @@ def summarize(devices: list, host: list, window: tuple,
         window_s=hi - lo, modules=modules,
         device_ops=[[n, s] for n, s in ops],
         idle_gaps=[[_label(g, hosts), g[1] - g[0]] for g in longest],
-        programs=devices[0] if devices else [])
+        programs=devices)
 
 
 def read_xplane(path: str):
-    """``(devices, host)`` event lists of one ``.xplane.pb``."""
+    """``(devices, host)`` of one ``.xplane.pb``: ``{ordinal: program
+    events}`` of each TPU plane (empty for a chip that ran nothing), and
+    the host events."""
     import jax
     data = jax.profiler.ProfileData.from_file(path)
-    devices, host = [], []
+    devices, host = {}, []
     for plane in data.planes:
         if plane.name.startswith(DEVICE_PREFIX):
+            chip = devices.setdefault(int(plane.name[len(DEVICE_PREFIX):]),
+                                      [])
             for line in plane.lines:
                 if line.name == MODULE_LINE:
-                    devices.append([Event(e.name, e.start_ns * 1e-9,
-                                          e.duration_ns * 1e-9)
-                                    for e in line.events])
+                    chip.extend(Event(e.name, e.start_ns * 1e-9,
+                                      e.duration_ns * 1e-9)
+                                for e in line.events)
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 host.extend(Event(e.name, e.start_ns * 1e-9,

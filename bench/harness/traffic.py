@@ -34,6 +34,14 @@ flight, so the agents move in steps; each step sends every group's
 and half its deep ones.  So every step, for every seed, holds the same
 mix of work; the seed draws ``t`` and ``shift``, which turn every
 grid's order and the pool's, and no job repeats within a run.
+
+On a fabric of ``n`` shards the agents split into ``n`` equal blocks,
+block ``s`` pinned to shard ``s`` (``bench/harness/target.py``).  Agent
+``i`` keeps the group ``i mod groups``; ``k`` and the agent's place among
+them count the group's agents in its block, ``S`` the block's steps, and
+the points turn by ``k·s``.  So a block of 16 agents repeats the one-chip
+loop of 16, each shard on other points at one step, and no shard sees a
+job twice within the run.
 """
 
 from __future__ import annotations
@@ -51,10 +59,12 @@ def _rng(seed: int, stream: int) -> np.random.Generator:
 class Traffic:
     """Jobs of one cell, from its traffic file, configuration and seed."""
 
-    def __init__(self, traffic: dict, config: dict, seed: int):
+    def __init__(self, traffic: dict, config: dict, seed: int,
+                 shards: int = 1):
         self.t = traffic
         self.config = config
         self.seed = seed
+        self.shards = shards
         self._ids = itertools.count()
 
     # -- the pipelines ---------------------------------------------------------
@@ -86,21 +96,29 @@ class Traffic:
                 "cv_k": c["cv_k"]}
 
     # -- the window --------------------------------------------------------------
+    def shard_of(self, agent_index: int) -> int:
+        """The shard agent ``agent_index``'s block is pinned to."""
+        return agent_index * self.shards // self.t["agents"]
+
     def agent_jobs(self, agent_index: int):
         """Endless job sequence of one closed-loop agent."""
         groups = self.groups()
+        shard = self.shard_of(agent_index)
+        block = [i for i in range(self.t["agents"])
+                 if self.shard_of(i) == shard]
+        members = [[i for i in block if i % len(groups) == grp]
+                   for grp in range(len(groups))]
         g = agent_index % len(groups)
         (preproc, model), points = groups[g]
-        k = len(range(g, self.t["agents"], len(groups)))
-        h = agent_index // len(groups)
+        k = len(members[g])
+        h = members[g].index(agent_index)
         pool = self.t["cv_seeds"]
-        steps = min(len(pts) // len(range(i, self.t["agents"], len(groups)))
-                    for i, (_, pts) in enumerate(groups)
-                    if i < self.t["agents"])
+        steps = min(len(pts) // len(m)
+                    for (_, pts), m in zip(groups, members) if m)
         rng = _rng(self.seed, 1)
         t, shift = int(rng.integers(1 << 16)), int(rng.integers(len(pool)))
         for j in itertools.count():
-            point = points[(k * (t + g + j) + h) % len(points)]
+            point = points[(k * (t + g + j + shard) + h) % len(points)]
             cv_seed = pool[(shift + j // steps) % len(pool)]
             yield self.job(f"agent-{agent_index}", preproc, model, point,
                            cv_seed, self.t["te_smoothing"])

@@ -14,8 +14,10 @@ and the files it names (``bench/configs/<config>.json``,
    through the service and lets every agent finish one job: set-up, timed
    as ``setup_s`` from process start to the window's start;
 3. drives the cell's traffic through ``StratumClient.submit`` on
-   ``connect("service", StratumConfig.make())`` for ``--seconds``, under
-   JAX's profiler with ``--trace 1``;
+   ``connect("service", StratumConfig.make())``, or on the fabric, one
+   shard per chip, where the configuration's ``client`` key asks for it
+   (``bench/harness/target.py``), for ``--seconds``, under JAX's profiler
+   with ``--trace 1``;
 4. closes the service, scores a sample of the window's jobs with the plain
    reference (``bench/reference``) and decides ``correct``;
 5. prints each compared number beside its limit on standard error, and
@@ -42,6 +44,7 @@ import tempfile  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "bench")
+# the longest wait for any one warm-up job to complete
 SETUP_TIMEOUT_S = 900.0
 
 
@@ -75,6 +78,16 @@ def load_cell(root: str, workload: str) -> tuple:
     except (OSError, KeyError, ValueError) as e:
         raise Refused(f"cannot read the benchmark's files: {e!r}", 2)
     return bench, cell, config, traffic
+
+
+def check_target(config: dict, cell: dict, traffic: dict) -> None:
+    """Refuse (exit 2), before any JAX work, a configuration whose
+    ``client`` does not fit the cell (``bench/harness/target.py``)."""
+    from bench.harness import target
+    try:
+        target.shards(config, cell["chips"], traffic["agents"])
+    except ValueError as e:
+        raise Refused(f"{cell['name']}: {e}", 2)
 
 
 def metrics_for(bench: dict, cell: str, kind: str) -> list:
@@ -150,11 +163,10 @@ class Context:
 def run_cell(root: str, bench: dict, cell: dict, config: dict,
              traffic: dict, seed: int, seconds: float, trace: bool,
              device: dict, peak: dict, t_start: float) -> dict:
-    from bench.harness import check, drive, probes, table
+    from bench.harness import check, drive, probes, table, target
     from bench.harness.jobs import build_batch
     from bench.harness.trace import Profiler
     from bench.harness.traffic import Traffic
-    from repro.client import StratumConfig, connect
 
     lake = os.environ["REPRO_DATA_LAKE"]
     table.write(config, seed, lake)
@@ -163,13 +175,17 @@ def run_cell(root: str, bench: dict, cell: dict, config: dict,
     def build(job):
         return build_batch(job, config["schema"], config["dataset"])
 
-    gen = Traffic(traffic, config, seed)
-    client = connect("service", StratumConfig.make())
+    n_shards = target.shards(config, cell["chips"], traffic["agents"])
+    gen = Traffic(traffic, config, seed, max(n_shards, 1))
+    client = target.connect(n_shards)
     try:
+        pins = target.pins(client, n_shards)
         rounds = gen.warmup_rounds()
-        drive.run_rounds(client, rounds, build, SETUP_TIMEOUT_S)
+        wait = drive.run_rounds(client, rounds, build, SETUP_TIMEOUT_S,
+                                pins)
         t_warm = time.perf_counter()
-        agents = [gen.agent_jobs(i) for i in range(traffic["agents"])]
+        n_agents = traffic["agents"]
+        agents = [gen.agent_jobs(i) for i in range(n_agents)]
         gc.collect()
         snaps, probe = {}, None
         profiler = Profiler(tempfile.mkdtemp(prefix="trace-") if trace
@@ -182,7 +198,8 @@ def run_cell(root: str, bench: dict, cell: dict, config: dict,
             snaps["setup_s"] = now - t_start
             print(f"set-up: start {t_table - t_start:.3f} s (imports, "
                   f"table), warm-up {t_warm - t_table:.3f} s "
-                  f"({sum(map(len, rounds))} jobs), ramp "
+                  f"({sum(map(len, rounds)) * len(pins)} jobs, the "
+                  f"longest wait {wait:.3f} s), ramp "
                   f"{now - t_warm:.3f} s", file=sys.stderr, flush=True)
             if trace:
                 probe = probes.install()
@@ -193,8 +210,10 @@ def run_cell(root: str, bench: dict, cell: dict, config: dict,
             if trace:
                 profiler.stop()
 
-        window = drive.closed_loop(client, agents, build, seconds,
-                                   on_open, on_close)
+        window = drive.closed_loop(
+            client, agents, build, seconds, on_open, on_close,
+            [pins[gen.shard_of(i)] for i in range(n_agents)]
+            if n_shards else None)
         if probe is not None:
             probe.remove()
         import jax
@@ -207,6 +226,18 @@ def run_cell(root: str, bench: dict, cell: dict, config: dict,
 
     done = window.completed_in_window()
     failed = [r for r in window.records if not r.ok]
+    if n_shards:
+        # the jobs ``pipelines_per_s`` counts, by the shard each ran on
+        per_shard = {pin[0]: 0 for pin in pins}
+        for r in window.completed():
+            per_shard[r.pin[0]] += 1
+        print(f"window jobs per shard: {per_shard}; drained "
+              f"{window.drained - window.end:.3f} s after the close",
+              file=sys.stderr, flush=True)
+    print(f"window: {len(window.completed())} jobs in super-batches "
+          + ", ".join(f"{n}@{sent:.2f}-{end:.2f}s"
+                      for n, sent, end in window.batches()),
+          file=sys.stderr, flush=True)
     checks = check.compare(window.completed(), lake, config,
                            traffic["check_per_family"], seed,
                            check.families(traffic))
@@ -252,6 +283,7 @@ def main(argv=None) -> int:
         with open(os.path.join(BENCH, "peaks.json")) as f:
             peaks = json.load(f)
         prepare_env(ROOT)
+        check_target(config, cell, traffic)
         try:
             import repro.client  # noqa: F401
         except ImportError as e:

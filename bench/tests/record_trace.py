@@ -58,7 +58,7 @@ def main(out: str) -> None:
     devices, host = trace.read_xplane(path)
     doc = {"window": trace.window_of(host),
            "devices": [[[e.name, e.start, e.dur] for e in evs]
-                       for evs in devices],
+                       for evs in devices.values()],
            "host": [[e.name, e.start, e.dur] for e in host]}
     with open(os.path.join(out, "tpu_trace_events.json"), "w") as f:
         json.dump(doc, f, indent=0)

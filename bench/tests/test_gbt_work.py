@@ -41,3 +41,19 @@ def test_same_bytes_for_dense_and_scatter_forms(dense, monkeypatch):
     ((shape, n_trees, depth),) = seen
     assert gbt_work.fit_bytes(*shape, n_trees, depth) == \
         gbt_work.fit_bytes(300, 5, 3, 2)
+
+
+def test_probe_records_each_fit_with_its_chip():
+    from repro.tabular import gbt
+
+    from bench.harness import probes
+
+    probe = probes.install()
+    try:
+        rng = np.random.default_rng(1)
+        gbt.fit_jax(rng.standard_normal((200, 4)), rng.standard_normal(200),
+                    n_trees=2, depth=2)
+    finally:
+        probe.remove()
+    ((_, n, F, n_trees, depth, chip),) = probe.fits
+    assert (n, F, n_trees, depth, chip) == (200, 4, 2, 2, 0)

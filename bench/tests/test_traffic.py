@@ -122,3 +122,34 @@ def test_warmup_same_for_every_seed_covers_shapes_and_repeats_no_job():
     xgb = {(j["preproc"], j["params"]["n_trees"], j["params"]["depth"],
             j["cv_seed"]) for j in warm if j["model"] == "gbt_xgboost"}
     assert len(xgb) == 2 * 4 * len(twins)
+
+
+def test_fabric_blocks_repeat_the_one_chip_loop_on_other_points():
+    """On four shards of 16 agents each, every shard's block is the
+    one-chip cell's loop: shard 0's the very same jobs, each other's the
+    same mix on other points, so at one step no two shards send the same
+    job, and no shard sees a job twice in 16 steps."""
+    config, traffic = _cell()
+    per, shards = traffic["agents"], 4
+    seed, n = 2 ** 33 + 9, 16
+    t = Traffic(dict(traffic, agents=per * shards), config, seed, shards)
+    agents = [t.agent_jobs(i) for i in range(per * shards)]
+    fabric = [[next(a) for _ in range(n)] for a in agents]
+    # agent i is pinned to shard floor(i * shards / agents)
+    assert [t.shard_of(i) for i in range(per * shards)] == \
+        [s for s in range(shards) for _ in range(per)]
+    blocks = [fabric[s * per:(s + 1) * per] for s in range(shards)]
+    assert [[_strip(j) for j in x] for x in blocks[0]] == \
+        [[_strip(j) for j in x] for x in _closed(seed, n)]
+    for step in range(n):
+        sent = [{_key(x[step]) for x in b} for b in blocks]
+        assert all(len(s) == per for s in sent)
+        assert len(set().union(*sent)) == per * shards
+    for b in blocks:                 # each shard's cache sees no repeat
+        keys = [_key(j) for x in b for j in x]
+        assert len(keys) == len(set(keys))
+    mix = lambda b: Counter((j["preproc"], j["model"],
+                             j["params"].get("n_trees"),
+                             j["params"].get("depth"))
+                            for x in b for j in x)
+    assert all(mix(b) == mix(blocks[0]) for b in blocks)
