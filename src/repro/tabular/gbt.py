@@ -57,25 +57,58 @@ def _subnormal(a: np.ndarray) -> bool:
                for b in (a[i:i + 4096] for i in range(0, len(a), 4096)))
 
 
-def _device_edges(X: np.ndarray, bins: np.ndarray):
-    """The float32 edges :func:`_digitize` compares ``X`` against, or None
-    where its bins could differ from ``bin_data(X, bins)``.
+def _device_edges(bins: np.ndarray):
+    """The float32 edges :func:`_digitize` compares float32 features
+    without a subnormal value against, or None where its bins could differ
+    from ``bin_data(X, bins)``.
 
     For a float32 ``x`` and a float64 edge ``t``, ``x >= t`` holds exactly
     when ``x >= c``, ``c`` the least float32 ``>= t``; and counting the
     edges ``x`` reaches is ``searchsorted(side="right")`` where each row of
-    edges ascends with any NaN last.  None for features that are not
-    float32, edges that do not so ascend (quantiles over ±inf give NaN
-    between numbers), and subnormal features or edges, which a TPU (and
-    XLA's CPU) flushes to zero."""
-    if X.dtype != np.float32 or _subnormal(X):
-        return None
+    edges ascends with any NaN last.  None for edges that do not so ascend
+    (quantiles over ±inf give NaN between numbers) and subnormal edges,
+    which a TPU (and XLA's CPU) flushes to zero, as it does subnormal
+    features."""
     if not np.all(np.isnan(bins[:, 1:]) | (bins[:, :-1] <= bins[:, 1:])):
         return None
     c = bins.astype(np.float32)
     low = c < bins
     c[low] = np.nextafter(c[low], np.float32(np.inf))
     return None if _subnormal(c) else c
+
+
+@jax.jit
+def _order_stats(X):
+    """The order statistics :func:`make_bins` interpolates between, for
+    float32 features ``(n, F)`` without a subnormal value: ``(lo, hi, m)``,
+    ``m`` each column's count of non-NaN values and ``lo``/``hi`` its
+    values of rank ``⌊(m−1)·k/N_BINS⌋`` and the next (the last where
+    ``m <= 1``) for ``k = 1..N_BINS-1``, each ``(N_BINS-1, F)``, in the
+    column sorted with NaN last.  Float32 widens to float64 exactly, so
+    they are ``nanquantile``'s own; the ranks are exact in int32."""
+    m = jnp.sum(~jnp.isnan(X), axis=0, dtype=jnp.int32)
+    k = jnp.arange(1, N_BINS, dtype=jnp.int32)[:, None]
+    lo = jnp.maximum((m - 1) * k // N_BINS, 0)
+    hi = jnp.minimum(lo + 1, jnp.maximum(m - 1, 0))
+    S = jnp.sort(X, axis=0)
+    return (jnp.take_along_axis(S, lo, axis=0),
+            jnp.take_along_axis(S, hi, axis=0), m)
+
+
+def _lerp_edges(lo, hi, m) -> np.ndarray:
+    """``make_bins``'s edges from :func:`_order_stats`: NumPy's linear
+    method and ``_lerp`` (``numpy/lib/_function_base_impl.py``) in float64,
+    so equal under ``==`` (a zero's sign may differ).  Gamma is the
+    fraction of the virtual index ``(m−1)·k/N_BINS``; a column with no
+    number gives NaN, its only order statistic."""
+    k = np.arange(1, N_BINS)[:, None]
+    m = np.asarray(m, dtype=np.int64)
+    g = (m - 1) * k % N_BINS / N_BINS
+    a, b = np.asarray(lo, np.float64), np.asarray(hi, np.float64)
+    diff = b - a
+    edges = a + diff * g
+    np.subtract(b, diff * (1 - g), out=edges, where=g >= 0.5)
+    return edges.T.copy()
 
 
 @partial(jax.jit, donate_argnums=0)
@@ -92,28 +125,58 @@ def _digitize(X, edges):
     return B
 
 
+@jax.jit
+def _take_rows(X, rows):
+    """A subsampling fit's kept rows, gathered on the device."""
+    return X[rows]
+
+
 def _put(*host) -> list:
     with span("stratum.gbt.put", bytes=sum(int(a.nbytes) for a in host)):
         return [jnp.asarray(a) for a in host]
 
 
-def _binned(X: np.ndarray, edges_of, n: int):
-    """``(bins, B)``: the float64 edges ``edges_of()`` gives (``n`` rows
-    went into them) and ``X``'s bin ids against them, on the device.
+def _binned(X: np.ndarray, bins=None, keep=None, sort: bool = False):
+    """``(bins, B)``: a fit's quantile edges over every row of ``X`` (a
+    predict passes its model's ``bins``) and the bin ids against them of
+    ``X``'s rows, those ``keep`` marks where given, on the device.
 
-    Float32 features are put as they are and digitized on the device; the
-    host digitizes anything else (:func:`_device_edges`) and puts the bin
-    ids.  The ``stratum.gbt.bin`` span holds the host's share — the edges,
-    and the digitize where the host does it — and the dispatch of
-    :func:`_digitize`, not its wait; its ``on`` says which digitized."""
-    X_dev = _put(X)[0] if X.dtype == np.float32 else None
-    with span("stratum.gbt.bin", n=n, F=X.shape[1], on="device") as sp:
-        bins = edges_of()
-        edges = _device_edges(X, bins)
+    Float32 features are put once, every row, and those without a
+    subnormal value are digitized on the device, the kept rows gathered
+    there; the host digitizes anything else, or where the edges fail
+    :func:`_device_edges`, and puts the bin ids.  A fit's edges are
+    ``make_bins`` on the host, but with ``sort`` (the TPU's form: XLA's CPU
+    sort is slower than ``nanquantile``) and float32 features without a
+    subnormal value they are :func:`_lerp_edges` of the device's
+    :func:`_order_stats`.  The ``stratum.gbt.bin`` span holds the host's
+    share, the wait for the order statistics and the dispatch of
+    :func:`_digitize`, not its wait; its ``on`` says which side digitized
+    and a fit's ``edges`` which side took the quantiles."""
+    fit = bins is None
+    X_dev = rows = None
+    if X.dtype == np.float32:
+        X_dev = _put(X)[0]
+        if keep is not None:
+            (rows,) = _put(np.flatnonzero(keep).astype(np.int32))
+    sort = sort and fit and X_dev is not None
+    with span("stratum.gbt.bin", n=X.shape[0], F=X.shape[1],
+              on="device") as sp:
+        # the device sorts while the host checks; subnormal features
+        # leave its answer unread
+        stats = _order_stats(X_dev) if sort else None
+        exact = X_dev is not None and not _subnormal(X)
+        if fit:
+            sort = sort and exact
+            sp.attrs["edges"] = "device" if sort else "host"
+            bins = _lerp_edges(*jax.device_get(stats)) if sort \
+                else make_bins(X)
+        edges = _device_edges(bins) if exact else None
         if edges is not None:
+            if rows is not None:
+                X_dev = _take_rows(X_dev, rows)
             return bins, _digitize(X_dev, edges)
         sp.attrs["on"] = "host"
-        B = bin_data(X, bins)
+        B = bin_data(X if keep is None else X[keep], bins)
     return bins, _put(B)[0]
 
 
@@ -348,20 +411,20 @@ def fit_jax(X: np.ndarray, y: np.ndarray, *, n_trees: int = 30,
             depth: int = 3, lr: float = 0.1, reg: float = 1.0,
             subsample: float = 1.0, seed: int = 0,
             dense: bool = False) -> np.ndarray:
-    # quantile edges on the host over every row, training compiled
+    # quantile edges over every row (from the device's sort in the TPU's
+    # form, ``dense``), training compiled
     n, F = X.shape
     base = float(np.mean(y))
+    keep = None
     if subsample < 1.0:
         # deterministic row subsample per seed (applied once — cheaper than
         # per-round; documented deviation of the fast tier); a row's bins
         # do not depend on the other rows, so only the kept rows are binned
         rng = np.random.default_rng(seed)
         keep = rng.random(n) < subsample
-        X_fit, y_fit = X[keep], y[keep]
-    else:
-        X_fit, y_fit = X, y
-    bins, B_dev = _binned(X_fit, lambda: make_bins(X), n)
-    (y_dev,) = _put(np.asarray(y_fit, dtype=np.float32))
+        y = y[keep]
+    bins, B_dev = _binned(X, keep=keep, sort=dense)
+    (y_dev,) = _put(np.asarray(y, dtype=np.float32))
     with span("stratum.gbt.fit", n=int(B_dev.shape[0]), F=F,
               n_trees=n_trees, depth=depth):
         feats, thrs, leaves = _fit_jax_binned(
@@ -395,7 +458,7 @@ def _predict_jax(B, feats, thrs, leaves, base, depth: int,
 def predict_jax(model: np.ndarray, X: np.ndarray, *,
                 dense: bool = False) -> np.ndarray:
     base, bins, feats, thrs, leaves, depth = unpack(model, X.shape[1])
-    _, B_dev = _binned(X, lambda: bins, X.shape[0])
+    _, B_dev = _binned(X, bins)
     dev = [B_dev, *_put(feats, thrs, leaves)]
     with span("stratum.gbt.predict", n=X.shape[0], n_trees=feats.shape[0],
               depth=depth):

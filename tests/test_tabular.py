@@ -176,6 +176,15 @@ def _digitize_case(name):
         col = (np.float32(1 / 3) * rng.integers(0, 9, n)
                + np.float32(1e-3) * rng.normal(size=n)).astype(np.float32)
         return _at_edges(col)
+    if name == "magnitude_jumps":
+        # order statistics 2**35 apart where gamma >= 0.5: ``b - a`` is
+        # inexact there, and NumPy's lerp from ``b`` rounds unlike one
+        # from ``a``
+        col = np.sort(rng.uniform(1, 2, n)).astype(np.float32)
+        lo, frac = np.divmod((n - 1) * np.arange(1, gbt.N_BINS), gbt.N_BINS)
+        for j in lo[2 * frac >= gbt.N_BINS][:3]:
+            col[j + 1:] *= np.float32(2.0 ** 35)
+        return rng.permutation(col)
     col = rng.normal(size=n).astype(np.float32)
     if name == "nan":
         col[::7] = np.nan
@@ -187,22 +196,32 @@ def _digitize_case(name):
         col[:] = 2.5
     elif name == "all_nan":
         col[:] = np.nan
+    elif name == "nan_99":
+        col[rng.random(n) < 0.99] = np.nan
+    elif name == "single_number":
+        col[1:] = np.nan
     return col
 
 
+DIGITIZE_CASES = ["at_edges", "unrepresentable_edges", "nan", "inf",
+                  "signed_zero", "constant", "all_nan", "nan_99",
+                  "single_number", "magnitude_jumps"]
+
+
+def _beside_other(col):
+    other = np.random.default_rng(4).normal(size=col.size)
+    return np.stack([col, other.astype(np.float32)], axis=1)
+
+
 @pytest.mark.filterwarnings("ignore::RuntimeWarning")
-@pytest.mark.parametrize("case", [
-    "at_edges", "unrepresentable_edges", "nan", "inf", "signed_zero",
-    "constant", "all_nan"])
+@pytest.mark.parametrize("case", DIGITIZE_CASES)
 def test_device_digitize_matches_host_bins_bit_for_bit(case):
     """``_digitize`` against the float32 ceilings of the float64 edges
     gives ``bin_data``'s bins exactly, beside an ordinary column."""
     import jax.numpy as jnp
-    col = _digitize_case(case)
-    other = np.random.default_rng(4).normal(size=col.size)
-    X = np.stack([col, other.astype(np.float32)], axis=1)
+    X = _beside_other(_digitize_case(case))
     bins = gbt.make_bins(X)
-    edges = gbt._device_edges(X, bins)
+    edges = gbt._device_edges(bins)
     assert edges is not None and edges.dtype == np.float32
     assert np.all(edges[~np.isnan(bins)] >= bins[~np.isnan(bins)])
     np.testing.assert_array_equal(
@@ -227,19 +246,47 @@ def _host_case(name):
 
 @pytest.mark.filterwarnings("ignore::RuntimeWarning")
 @pytest.mark.parametrize("case", [
+    *DIGITIZE_CASES, "subnormal_edge", "edges_not_ascending"])
+def test_device_order_statistics_give_make_bins_edges_bit_for_bit(case):
+    """NumPy's lerp on the host between the order statistics of the
+    device's sort gives ``make_bins``'s float64 edges under ``==`` (NaN
+    equal to NaN), ±inf, ties, NaN and columns of one number or none
+    among them."""
+    import jax
+    X = (_host_case(case) if case in ("subnormal_edge", "edges_not_ascending")
+         else _beside_other(_digitize_case(case)))
+    edges = gbt._lerp_edges(*jax.device_get(gbt._order_stats(X)))
+    assert edges.dtype == np.float64
+    np.testing.assert_array_equal(edges, gbt.make_bins(X))
+
+
+def _bin_spans(sink) -> list:
+    return [(s[5]["on"], s[5].get("edges")) for s in sink
+            if s[2] == "stratum.gbt.bin"]
+
+
+@pytest.mark.filterwarnings("ignore::RuntimeWarning")
+@pytest.mark.parametrize("case", [
     "float64", "subnormal_feature", "subnormal_edge", "edges_not_ascending"])
 def test_features_the_device_cannot_digitize_exactly_bin_on_the_host(case):
+    """Both forms digitize on the host here.  The TPU's form sorts on the
+    device where the features allow it, and the CPU's does not; both
+    give the same edges."""
     X = _host_case(case)
     bins = gbt.make_bins(X)
-    assert gbt._device_edges(X, bins) is None
-    sink: list = []
+    assert X.dtype != np.float32 or gbt._subnormal(X) \
+        or gbt._device_edges(bins) is None
+    sorted_on_device = case in ("subnormal_edge", "edges_not_ascending")
     from repro.core import spans
-    with spans.collect(sink):
-        model = gbt.fit_jax(X, X[:, 0].astype(np.float64), n_trees=2,
-                            depth=2)
-        gbt.predict_jax(model, X)
-    assert [s[5]["on"] for s in sink if s[2] == "stratum.gbt.bin"] == \
-        ["host", "host"]
+    for dense in (False, True):
+        sink: list = []
+        with spans.collect(sink):
+            model = gbt.fit_jax(X, X[:, 0].astype(np.float64), n_trees=2,
+                                depth=2, dense=dense)
+            gbt.predict_jax(model, X, dense=dense)
+        edges = "device" if dense and sorted_on_device else "host"
+        assert _bin_spans(sink) == [("host", edges), ("host", None)]
+        np.testing.assert_array_equal(gbt.unpack(model, 3)[1], bins)
 
 
 @pytest.mark.parametrize("dense", [False, True])
@@ -248,7 +295,7 @@ def test_fit_and_predict_on_float32_features_match_host_binning(
         dense, subsample):
     """``fit_jax`` packs and ``predict_jax`` outputs on float32 features,
     digitized on the device, equal those of the host-binned path bit for
-    bit."""
+    bit, the TPU's form with its edges from the device's sort."""
     import jax.numpy as jnp
     rng = np.random.default_rng(6)
     X = rng.normal(size=(800, 7)).astype(np.float32)
@@ -257,10 +304,15 @@ def test_fit_and_predict_on_float32_features_match_host_binning(
     y = X[:, 0] * 2.0 + np.sin(np.nan_to_num(X[:, 3])) \
         + 0.1 * rng.normal(size=800)
     T, depth, lr, reg, seed = 6, 3, 0.1, 1.0, 11
-    model = gbt.fit_jax(X, y, n_trees=T, depth=depth, lr=lr, reg=reg,
-                        subsample=subsample, seed=seed, dense=dense)
+    sink: list = []
+    from repro.core import spans
+    with spans.collect(sink):
+        model = gbt.fit_jax(X, y, n_trees=T, depth=depth, lr=lr, reg=reg,
+                            subsample=subsample, seed=seed, dense=dense)
+    # the TPU's form takes the edges from the device's sort
+    assert _bin_spans(sink) == [("device", "device" if dense else "host")]
     bins = gbt.make_bins(X)
-    assert gbt._device_edges(X, bins) is not None
+    assert gbt._device_edges(bins) is not None
     B, y_fit = gbt.bin_data(X, bins), y
     if subsample < 1.0:
         keep = np.random.default_rng(seed).random(800) < subsample
